@@ -83,8 +83,6 @@ def _deposit_stage_times(sim: FleetSim, masks: np.ndarray) -> dict:
     ``segment_sum`` path ("segments") on the identical COO triples.
     Both run under x64 like the fused launch itself.
     """
-    from jax.experimental import enable_x64
-
     from repro.kernels import ops as kernel_ops
 
     F = masks.shape[0]
@@ -94,7 +92,7 @@ def _deposit_stage_times(sim: FleetSim, masks: np.ndarray) -> dict:
              + sim._f_rowc[cid].astype(np.int32))
     bins = sim._f_bins0[cid]
     vals = sim._f_work[cid] * sim._f_fin0[cid]
-    with enable_x64():
+    with jax.enable_x64():
         rows_d = jnp.asarray(fprow)
         bins_d = jnp.asarray(bins.astype(np.int64))
         vals_d = jnp.asarray(vals)

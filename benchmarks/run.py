@@ -118,6 +118,8 @@ def main() -> None:
             print(f"{name:<{width}}  {summary[0] if summary else ''}")
         return
 
+    from repro.launch.cache import setup_compile_cache
+    setup_compile_cache()
     selected = []
     for item in (args.only or list(suite)):
         selected += [s for s in item.split(",") if s]

@@ -1,28 +1,13 @@
-"""Version compatibility shims for the jax API surface this repo uses.
+"""Thin wrappers over the jax API surface this repo uses (jax >= 0.9).
 
-The repo targets a range of jax versions:
-
-- ``shard_map`` graduated from ``jax.experimental.shard_map`` to the
-  top-level ``jax.shard_map`` (jax >= 0.6), and its replication-check
-  keyword was renamed ``check_rep`` -> ``check_vma`` along the way.
-- ``jax.lax.axis_size`` does not exist on 0.4.x (there the static axis
-  size comes from ``jax.core.axis_frame``).
-- ``Compiled.cost_analysis()`` returned a single-element list on 0.4.x
-  and a flat dict on newer jax.
-
-Call sites are written once against the newest spelling and routed
-through the shims here.
+- ``shard_map`` is ``jax.shard_map``; its replication-check keyword is
+  ``check_vma`` (``check_rep`` is accepted as its older alias).
+- ``axis_size`` is ``jax.lax.axis_size``.
+- ``cost_analysis`` returns ``Compiled.cost_analysis()`` as a flat dict.
 """
 from __future__ import annotations
 
 import jax
-
-try:  # jax >= 0.6: top-level export
-    _shard_map_impl = jax.shard_map
-    _CHECK_KW = "check_vma"
-except AttributeError:  # jax 0.4.x: experimental module, check_rep kwarg
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-    _CHECK_KW = "check_rep"
 
 
 def shard_map(f, mesh, in_specs, out_specs, *,
@@ -30,31 +15,24 @@ def shard_map(f, mesh, in_specs, out_specs, *,
               check_rep: bool | None = None, **kwargs):
     """Map ``f`` over shards of its inputs (see ``jax.shard_map``).
 
-    ``check_vma`` (new name) and ``check_rep`` (pre-0.6 name) are the same
-    flag; pass either.  Defaults to the underlying implementation's default
-    when both are None.
+    ``check_vma`` and its older name ``check_rep`` are the same flag;
+    pass either.  Defaults to ``jax.shard_map``'s default when both are
+    None.
     """
     if check_vma is not None and check_rep is not None and check_vma != check_rep:
         raise ValueError("check_vma and check_rep are aliases; got conflicting values")
     flag = check_vma if check_vma is not None else check_rep
     if flag is not None:
-        kwargs[_CHECK_KW] = flag
-    return _shard_map_impl(f, mesh=mesh, in_specs=in_specs,
-                           out_specs=out_specs, **kwargs)
+        kwargs["check_vma"] = flag
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, **kwargs)
 
 
 def axis_size(axis_name) -> int:
-    """Static size of a mapped axis (``jax.lax.axis_size`` on new jax)."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    from jax import core
-    frame = core.axis_frame(axis_name)   # 0.4.37 returns the size itself;
-    return frame if isinstance(frame, int) else frame.size
+    """Static size of a mapped axis."""
+    return jax.lax.axis_size(axis_name)
 
 
 def cost_analysis(compiled) -> dict:
-    """``Compiled.cost_analysis()`` normalized to one flat dict."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0]
-    return cost
+    """``Compiled.cost_analysis()`` as one flat dict."""
+    return compiled.cost_analysis()

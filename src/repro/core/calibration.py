@@ -58,7 +58,7 @@ TABLE_DIR = Path(__file__).resolve().parent / "calibration_tables"
 #: Decode batch sizes the gateway kernel is swept over.
 DEFAULT_BATCHES = (1, 2, 4, 8, 16, 32)
 
-#: TPU v5e bytes-per-FLOP balance (HBM_BW / PEAK_FLOPS).  Satellite memory
+#: TPU v5e bytes-per-FLOP balance (HBM bytes/s over bf16 FLOP/s).  Satellite memory
 #: bandwidth defaults to the onboard FLOP rate times this balance, keeping
 #: the arithmetic-intensity threshold of the satellite roofline identical
 #: to the measured accelerator's.

@@ -35,7 +35,12 @@ from jax.experimental.pallas import tpu as pltpu
 
 def _deposit_kernel(rows_ref, cols_ref, vals_ref, o_ref, acc_ref, *,
                     n_chunk_blocks: int):
-    """One (row-tile, time-tile, chunk-block) grid step."""
+    """One (row-tile, time-tile, chunk-block) grid step.
+
+    ``rows_ref`` is a lane vector (1, bc); ``cols_ref`` and ``vals_ref``
+    are sublane vectors (bc, 1), so both one-hots come out in the
+    layout the MXU contraction wants without a transpose.
+    """
     r = pl.program_id(0)
     t = pl.program_id(1)
     c = pl.program_id(2)
@@ -44,24 +49,30 @@ def _deposit_kernel(rows_ref, cols_ref, vals_ref, o_ref, acc_ref, *,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    rows = rows_ref[0]                                   # (bc,) int32
-    cols = cols_ref[0]
-    vals = vals_ref[0]
-    bc = rows.shape[0]
+    rows = rows_ref[...]                                 # (1, bc) int32
+    cols = cols_ref[...]                                 # (bc, 1) int32
+    vals = vals_ref[...]                                 # (bc, 1)
+    bc = cols.shape[0]
     br, bt = acc_ref.shape
     dtype = acc_ref.dtype
     # Chunks outside this (row, time) tile match no one-hot lane: zero
     # contribution, no separate masking pass.
-    iota_r = jax.lax.broadcasted_iota(jnp.int32, (bc, br), 1)
-    oh_rows = ((rows[:, None] - r * br) == iota_r).astype(dtype)
+    iota_r = jax.lax.broadcasted_iota(jnp.int32, (br, bc), 0)
+    oh_rows = ((rows - r * br) == iota_r).astype(dtype)  # (br, bc)
     iota_t = jax.lax.broadcasted_iota(jnp.int32, (bc, bt), 1)
-    oh_cols = ((cols[:, None] - t * bt) == iota_t).astype(dtype)
-    acc_ref[...] += jnp.dot(oh_rows.T, vals[:, None] * oh_cols,
-                            preferred_element_type=dtype)
+    oh_cols = ((cols - t * bt) == iota_t).astype(dtype)  # (bc, bt)
+    # HIGHEST: a default-precision f32 matmul rounds vals to bf16.
+    acc_ref[...] += jnp.dot(oh_rows, vals * oh_cols,
+                            preferred_element_type=dtype,
+                            precision=jax.lax.Precision.HIGHEST)
 
     @pl.when(c == n_chunk_blocks - 1)
     def _flush():
         o_ref[...] = acc_ref[...].astype(o_ref.dtype)
+
+
+def _round_up(x: int, m: int) -> int:
+    return -(-x // m) * m
 
 
 def _pad_to(x: jnp.ndarray, mult: int, fill) -> jnp.ndarray:
@@ -165,32 +176,37 @@ def deposit(
     if rows.shape[0] == 0:
         # Zero chunk blocks would leave the output buffer unwritten.
         return jnp.zeros((n_rows, n_cols), dtype=vals.dtype)
-    br = min(block_r, n_rows)
-    n_rows_pad = -(-n_rows // br) * br
-    bt = min(block_t, n_cols)
-    n_cols_pad = -(-n_cols // bt) * bt
-    bc = min(block_c, max(8, rows.shape[0]))
+    # Tiles obey the TPU's (8, 128) layout: rows pad to a multiple of 8,
+    # bins and chunks to multiples of 128.
+    br = min(block_r, _round_up(n_rows, 8))
+    n_rows_pad = _round_up(n_rows, br)
+    bt = min(block_t, _round_up(n_cols, 128))
+    n_cols_pad = _round_up(n_cols, bt)
+    bc = min(block_c, _round_up(rows.shape[0], 128))
     # Padding chunks target column n_cols_pad (outside every tile) with
     # zero weight, so they deposit nothing.
     rows_p = _pad_to(rows.astype(jnp.int32), bc, 0)
     cols_p = _pad_to(cols.astype(jnp.int32), bc, n_cols_pad)
     vals_p = _pad_to(vals, bc, 0)
-    n_blocks = rows_p.shape[0] // bc
+    n_chunks = rows_p.shape[0]
+    n_blocks = n_chunks // bc
     grid = (n_rows_pad // br, n_cols_pad // bt, n_blocks)
 
     out = pl.pallas_call(
         functools.partial(_deposit_kernel, n_chunk_blocks=n_blocks),
         grid=grid,
+        # Block indices are int32 even when the caller traces under x64
+        # (the fused fleet launch does): Mosaic rejects i64 indices.
         in_specs=[
-            pl.BlockSpec((1, bc), lambda r, t, c: (c, 0)),
-            pl.BlockSpec((1, bc), lambda r, t, c: (c, 0)),
-            pl.BlockSpec((1, bc), lambda r, t, c: (c, 0)),
+            pl.BlockSpec((1, bc), lambda r, t, c: (jnp.int32(0), c)),
+            pl.BlockSpec((bc, 1), lambda r, t, c: (c, jnp.int32(0))),
+            pl.BlockSpec((bc, 1), lambda r, t, c: (c, jnp.int32(0))),
         ],
         out_specs=pl.BlockSpec((br, bt), lambda r, t, c: (r, t)),
         out_shape=jax.ShapeDtypeStruct((n_rows_pad, n_cols_pad),
                                        vals.dtype),
         scratch_shapes=[pltpu.VMEM((br, bt), vals.dtype)],
         interpret=interpret,
-    )(rows_p.reshape(n_blocks, bc), cols_p.reshape(n_blocks, bc),
-      vals_p.reshape(n_blocks, bc))
+    )(rows_p.reshape(1, n_chunks), cols_p.reshape(n_chunks, 1),
+      vals_p.reshape(n_chunks, 1))
     return out[:n_rows, :n_cols]

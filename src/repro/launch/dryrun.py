@@ -42,6 +42,8 @@ from repro.launch.steps import (input_specs, make_prefill_step,
 from repro.models import Parallel
 
 OUT_DIR_DEFAULT = "experiments/dryrun"
+#: The production meshes are TPU v5e pods; their roofline uses its peaks.
+TARGET_DEVICE_KIND = "TPU v5 lite"
 
 
 def _named(mesh, spec_tree):
@@ -182,7 +184,8 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, out_dir: str,
         # raw cost_analysis does not (see hlo_analysis.py docstring)
         hcost = hlo_analysis.analyze(hlo, mesh.size)
         roof = rl.derive_from_hlo_cost(hcost, mesh.size,
-                                       rl.model_flops(cfg, shape))
+                                       rl.model_flops(cfg, shape),
+                                       TARGET_DEVICE_KIND)
         record.update({
             "status": "ok",
             "n_devices": mesh.size,

@@ -1,10 +1,11 @@
 """Roofline term derivation from compiled dry-run artifacts.
 
-Three terms per (arch x shape x mesh), in seconds (TPU v5e constants):
+Three terms per (arch x shape x mesh), in seconds, from the peaks of the
+chip's ``device_kind`` (:data:`DEVICE_PEAKS`):
 
-    compute    = HLO_FLOPs_per_chip / 197e12          (bf16 MXU peak)
-    memory     = HLO_bytes_per_chip / 819e9           (HBM bandwidth)
-    collective = collective_bytes_per_chip / 50e9     (per-link ICI)
+    compute    = HLO_FLOPs_per_chip / peak bf16 FLOP/s
+    memory     = HLO_bytes_per_chip / HBM bandwidth
+    collective = collective_bytes_per_chip / per-link ICI bandwidth
 
 ``cost_analysis()`` supplies per-chip FLOPs/bytes (the compiled module is
 the per-device SPMD program).  Collective bytes are NOT in cost_analysis —
@@ -25,9 +26,32 @@ import re
 from repro.configs.shapes import ShapeSpec
 from repro.models.config import ModelConfig
 
-PEAK_FLOPS = 197e12          # bf16 / chip
-HBM_BW = 819e9               # bytes/s / chip
-ICI_BW = 50e9                # bytes/s / link
+
+
+@dataclasses.dataclass(frozen=True)
+class DevicePeaks:
+    flops: float        # bf16 FLOP/s per chip
+    hbm_bw: float       # HBM bytes/s per chip
+    ici_bw: float       # interconnect bytes/s per link
+
+
+#: Published per-chip peaks keyed by ``jax.Device.device_kind``.
+#: TPU v5e: Google Cloud documentation, "TPU v5e" — 197 TFLOP/s bf16,
+#: 819 GB/s HBM, 1,600 Gbit/s interconnect over 4 links (50 GB/s each).
+DEVICE_PEAKS = {
+    "TPU v5 lite": DevicePeaks(flops=197e12, hbm_bw=819e9, ici_bw=50e9),
+}
+
+
+def device_peaks(device_kind: str) -> DevicePeaks:
+    """Peaks of ``device_kind``; an unknown kind is an error."""
+    try:
+        return DEVICE_PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(f"no published peaks for device kind "
+                         f"{device_kind!r}; known: {sorted(DEVICE_PEAKS)}"
+                         ) from None
+
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "f16": 2, "bf16": 2,
@@ -161,6 +185,7 @@ class Roofline:
     coll_bytes_per_chip: float
     n_devices: int
     model_flops_total: float
+    peak_flops: float
 
     @property
     def dominant(self) -> str:
@@ -185,7 +210,7 @@ class Roofline:
         1.0 means the step time is fully explained by MODEL_FLOPS at peak
         MXU throughput; less means the dominant term (or wasted FLOPs) is
         costing wall-clock."""
-        ideal = self.model_flops_total / (self.n_devices * PEAK_FLOPS)
+        ideal = self.model_flops_total / (self.n_devices * self.peak_flops)
         return ideal / self.bound_time_s if self.bound_time_s else 0.0
 
     def asdict(self):
@@ -203,32 +228,36 @@ class Roofline:
 
 
 def derive(cost: dict, coll: CollectiveStats, n_devices: int,
-           model_flops_total: float) -> Roofline:
+           model_flops_total: float, device_kind: str) -> Roofline:
+    peaks = device_peaks(device_kind)
     flops = float(cost.get("flops", 0.0))
     byts = float(cost.get("bytes accessed", 0.0))
     return Roofline(
-        compute_s=flops / PEAK_FLOPS,
-        memory_s=byts / HBM_BW,
-        collective_s=coll.bytes_per_chip / ICI_BW,
+        compute_s=flops / peaks.flops,
+        memory_s=byts / peaks.hbm_bw,
+        collective_s=coll.bytes_per_chip / peaks.ici_bw,
         flops_per_chip=flops,
         bytes_per_chip=byts,
         coll_bytes_per_chip=coll.bytes_per_chip,
         n_devices=n_devices,
         model_flops_total=model_flops_total,
+        peak_flops=peaks.flops,
     )
 
 
-def derive_from_hlo_cost(hlo_cost, n_devices: int,
-                         model_flops_total: float) -> Roofline:
+def derive_from_hlo_cost(hlo_cost, n_devices: int, model_flops_total: float,
+                         device_kind: str) -> Roofline:
     """Roofline terms from the loop-aware HLO walker (the accurate path —
     raw cost_analysis counts while-loop bodies once; see hlo_analysis.py)."""
+    peaks = device_peaks(device_kind)
     return Roofline(
-        compute_s=hlo_cost.flops / PEAK_FLOPS,
-        memory_s=hlo_cost.bytes_accessed / HBM_BW,
-        collective_s=hlo_cost.collective_bytes / ICI_BW,
+        compute_s=hlo_cost.flops / peaks.flops,
+        memory_s=hlo_cost.bytes_accessed / peaks.hbm_bw,
+        collective_s=hlo_cost.collective_bytes / peaks.ici_bw,
         flops_per_chip=hlo_cost.flops,
         bytes_per_chip=hlo_cost.bytes_accessed,
         coll_bytes_per_chip=hlo_cost.collective_bytes,
         n_devices=n_devices,
         model_flops_total=model_flops_total,
+        peak_flops=peaks.flops,
     )

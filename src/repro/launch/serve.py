@@ -27,7 +27,10 @@ The paper's kind is inference, so this is the headline end-to-end driver:
 from __future__ import annotations
 
 import argparse
+import dataclasses
+import functools
 import time
+import warnings
 
 import jax
 import jax.numpy as jnp
@@ -41,23 +44,44 @@ from repro.core import (ActivationModel, ComputeConfig, Constellation,
                         rand_intra_cg_plan, sample_topology,
                         simulate_token_generation_legacy, spacemoe_plan)
 from repro.distributed import migration, replan_on_failure
-from repro.launch.steps import make_serve_step
-from repro.models import (Parallel, forward, init_params, prefill,
+from repro.launch.cache import setup_compile_cache
+from repro.launch.steps import make_prefill_step, make_serve_step
+from repro.models import (ModelConfig, Parallel, forward, init_params,
                           random_batch)
-from repro.models.moe import apply_placement
 
 
 def calibrate_router_stats(cfg, params, batch) -> np.ndarray | None:
     """(n_scan_units, E) expert-selection counts from one forward pass."""
     if not cfg.has_moe:
         return None
-    _, _, counts = forward(cfg, params, batch, return_router_stats=True)
+    fwd = jax.jit(functools.partial(forward, cfg, return_router_stats=True))
+    _, _, counts = fwd(params, batch)
     return np.asarray(counts)
+
+
+@functools.partial(jax.jit, static_argnums=(2,), donate_argnums=(0,))
+def _permute_units(stack, perms, axis: int):
+    """``stack[u] = take(stack[u], perms[u], axis)`` for every unit u.
+
+    The loop rewrites one unit at a time into the donated stack, so the
+    permutation never holds a second copy of the expert weights.
+    """
+    def body(u, s):
+        unit = jax.lax.dynamic_index_in_dim(s, u, 0, keepdims=False)
+        unit = jnp.take(unit, perms[u], axis=axis)
+        return jax.lax.dynamic_update_index_in_dim(s, unit, u, 0)
+
+    return jax.lax.fori_loop(0, stack.shape[0], body, stack)
 
 
 def plan_and_apply_placement(cfg, params, counts: np.ndarray,
                              ep_ring: int = 16):
-    """Per-unit Theorem-1 device placement, applied to the expert stacks."""
+    """Per-unit Theorem-1 device placement, applied to the expert stacks.
+
+    The router columns and the expert stacks of every MoE block are
+    permuted in place (their buffers are donated): ``params`` must not
+    be used afterwards.
+    """
     e = cfg.n_experts
     ring = TorusSpec(shape=(min(ep_ring, e),), wrap=True)
     plans, costs = [], {"theorem1": 0.0, "identity": 0.0}
@@ -71,31 +95,25 @@ def plan_and_apply_placement(cfg, params, counts: np.ndarray,
         costs["identity"] += expected_dispatch_cost(base, w, cfg.top_k)
         plans.append(plan)
         perms.append(plan.expert_perm)
-    perms = np.stack(perms)                      # (U, E)
+    perms = jnp.asarray(np.stack(perms), jnp.int32)     # (U, E)
 
-    units = params["units"]
-
-    def permute_stacked(ffn):
-        router = jnp.stack([ffn["router"][u][:, perms[u]]
-                            for u in range(perms.shape[0])])
-        out = dict(ffn, router=router)
-        for k in ("w_gate", "w_up", "w_down"):
-            out[k] = jnp.stack([ffn[k][u][perms[u]]
-                                for u in range(perms.shape[0])])
-        return out
-
-    new_units = dict(units)
-    for bname, bparams in units.items():
-        if isinstance(bparams, dict) and "ffn" in bparams \
-                and "router" in bparams["ffn"]:
-            nb = dict(bparams)
-            nb["ffn"] = permute_stacked(bparams["ffn"])
-            new_units[bname] = nb
+    new_units = dict(params["units"])
+    with warnings.catch_warnings():
+        # CPU jit may decline the donation; the permutation is unchanged.
+        warnings.filterwarnings("ignore", message=".*[Dd]onat")
+        for bname, bparams in new_units.items():
+            if isinstance(bparams, dict) and "ffn" in bparams \
+                    and "router" in bparams["ffn"]:
+                ffn = dict(bparams["ffn"])
+                ffn["router"] = _permute_units(ffn["router"], perms, 1)
+                for k in ("w_gate", "w_up", "w_down"):
+                    ffn[k] = _permute_units(ffn[k], perms, 0)
+                new_units[bname] = dict(bparams, ffn=ffn)
     params = dict(params, units=new_units)
     return params, plans, costs
 
 
-def main(argv=None) -> dict:
+def parse_args(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="llama-moe-3.5b")
     ap.add_argument("--smoke", action="store_true")
@@ -163,11 +181,29 @@ def main(argv=None) -> dict:
                          "federation row plus one row per member)")
     ap.add_argument("--fail-device", type=int, default=-1,
                     help="elastic demo: fail this EP device and re-plan")
-    args = ap.parse_args(argv)
+    return ap.parse_args(argv)
 
+
+def main(argv=None) -> dict:
+    args = parse_args(argv)
+    setup_compile_cache()
     cfg = smoke_config(args.arch) if args.smoke else get_config(args.arch)
+    return run(cfg, args)
+
+
+def run(cfg: ModelConfig, args: argparse.Namespace) -> dict:
+    """Serve ``cfg`` under the parsed ``args`` (phases 1-5 above).
+
+    Returns the printed results plus what a caller needs to check them:
+    the served ``params``, the ``prompt`` and ``generated`` tokens, the
+    ``step_logits`` (B, decode_tokens + 1, V) of prefill and each decode
+    step, and with ``--traffic`` the scenario outcome (``fleet``).
+    """
+    # Inference keeps no master copy: weights live in the compute dtype.
+    cfg = dataclasses.replace(cfg, param_dtype=cfg.compute_dtype)
     par = Parallel(mesh=None)
-    params = init_params(cfg, jax.random.PRNGKey(args.seed))
+    params = jax.jit(functools.partial(init_params, cfg))(
+        jax.random.PRNGKey(args.seed))
     out: dict = {"arch": cfg.name}
 
     # ---- 1-2: calibrate + place ---------------------------------------
@@ -200,34 +236,50 @@ def main(argv=None) -> dict:
     batch = random_batch(cfg, args.batch, args.prompt_len, seed=args.seed)
     prompt = {k: v for k, v in batch.items() if k != "labels"}
     max_len = args.prompt_len + args.decode_tokens + 1
-    logits, cache = prefill(cfg, params, prompt, max_len=max_len, par=par)
-    serve_step = jax.jit(make_serve_step(cfg, par), donate_argnums=(1,))
+    t0 = time.perf_counter()
+    prefill_fn = jax.jit(make_prefill_step(cfg, par, max_len)) \
+        .lower(params, prompt).compile()
+    compile_s = time.perf_counter() - t0
+    logits, cache = prefill_fn(params, prompt)
     tok = jnp.argmax(logits, -1)[:, None].astype(jnp.int32)
     pos = jnp.full((args.batch,), args.prompt_len, jnp.int32)
     emb = (jnp.ones((args.batch, 1, cfg.d_model), jnp.float32)
            if cfg.frontend == "audio" else None)
+    t0 = time.perf_counter()
+    serve_step = jax.jit(make_serve_step(cfg, par), donate_argnums=(1,)) \
+        .lower(params, cache, tok, pos, emb).compile()
+    compile_s += time.perf_counter() - t0
+    out["compile_s"] = compile_s
     generated = [np.asarray(tok)]
-    t0 = time.time()
+    step_logits = [logits]
+    t0 = time.perf_counter()
     for _ in range(args.decode_tokens):
         tok, logits, cache = serve_step(params, cache, tok, pos, emb)
         pos = pos + 1
         generated.append(np.asarray(tok))
+        step_logits.append(logits)
     jax.block_until_ready(logits)
-    dt = time.time() - t0
+    dt = time.perf_counter() - t0
     toks = args.batch * args.decode_tokens
     out["tokens_per_s"] = toks / dt
-    gen = np.concatenate(generated, axis=1)
-    assert np.isfinite(np.asarray(logits)).all()
+    out["params"] = params
+    out["prompt"] = prompt
+    out["generated"] = np.concatenate(generated, axis=1)
+    out["step_logits"] = np.stack(
+        [np.asarray(lg, np.float32) for lg in step_logits], axis=1)
+    assert np.isfinite(out["step_logits"]).all()
     print(f"[serve] {toks} tokens in {dt:.2f}s -> {out['tokens_per_s']:.1f} tok/s "
-          f"(host mesh; see dry-run for production-mesh compilation)")
+          f"on {jax.devices()[0].device_kind} (compile {compile_s:.1f}s)")
 
     # ---- 4: space-network latency accounting ---------------------------
     if (args.space_sim or args.traffic) and cfg.has_moe:
-        ccfg = ConstellationConfig.scaled(12, 16, n_slots=20)
+        n_layers = counts.shape[0]
+        # Each layer's subnet needs its own slot on a plane's ring
+        # (N_y >= L), so deep models get longer planes.
+        ccfg = ConstellationConfig.scaled(12, max(16, n_layers), n_slots=20)
         con = Constellation(ccfg)
         rng = np.random.default_rng(1)
         topo = sample_topology(con, LinkConfig(token_dim=cfg.d_model), rng)
-        n_layers = counts.shape[0]
         activ = ActivationModel.from_router_counts(counts, cfg.top_k)
         wl = MoEWorkload(
             d_model=cfg.d_model, n_heads=cfg.n_heads,
@@ -259,8 +311,6 @@ def main(argv=None) -> dict:
               f"({cg.mean_s/sm.mean_s:.2f}x reduction)")
 
         if args.traffic:
-            import dataclasses
-
             from repro.traffic import (AdmissionConfig, ReplanConfig,
                                        build_ground_segment, format_table,
                                        get_scenario, run_scenario)
@@ -314,6 +364,7 @@ def main(argv=None) -> dict:
                                constellation=con,
                                rate_scale=args.rate_scale, ctrl=args.ctrl,
                                **sim_kwargs)
+            out["fleet"] = res
             rows = res.result.table(sc.slo, scenario=sc.name)
             if res.post_failure is not None:
                 rows += res.post_failure.table(

@@ -47,7 +47,6 @@ import warnings
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64 as _x64
 
 from .batching import effective_work_np
 from .ground import GroundSegment, rank_constellations
@@ -383,7 +382,7 @@ class FederationSim:
         s0 = self.sims[0]
         qcfg = s0.qcfg
         base = self._stacked_consts()
-        with _x64():
+        with jax.enable_x64():
             d = {}
             for key, a in base.items():
                 if key in ("gw_rows_bin", "exp_rows_bin"):
@@ -535,7 +534,7 @@ class FederationSim:
         """Device side of a launch: move the prepared chunk stream to
         the device and run the fused kernel once."""
         s0 = self.sims[0]
-        with _x64(), warnings.catch_warnings():
+        with jax.enable_x64(), warnings.catch_warnings():
             warnings.filterwarnings("ignore", message=".*[Dd]onat")
             out = _fused_exec(
                 self._device_consts(prep["n_sweep"]),

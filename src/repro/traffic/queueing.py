@@ -70,7 +70,7 @@ Two execution paths share that precompute:
   equal shapes — every ``run_many`` rate, every re-placement
   decide/evaluate round — reuse one compile cache entry.  Dtype policy
   mirrors the host path exactly: schedules/bins/deposits in float64
-  (``jax.experimental.enable_x64`` scoped to these launches), the
+  (``jax.enable_x64`` scoped to these launches), the
   backlog scan in float32 — the downcast ``run_legacy``'s jitted scans
   have always applied — so the two paths agree to the last bit in
   practice;
@@ -88,7 +88,6 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64 as _x64
 
 from repro.core import (ScheduleBatch, evaluate_schedules,
                         schedule_ingress_offsets)
@@ -2066,7 +2065,7 @@ class FleetSim:
         if self._dev is not None:
             return self._dev
         qcfg = self.qcfg
-        with _x64():
+        with jax.enable_x64():
             d = dict(
                 dt=jnp.asarray(float(qcfg.dt_s)),
                 cap32=jnp.asarray(float(qcfg.buffer_s), dtype=jnp.float32),
@@ -2408,7 +2407,7 @@ class FleetSim:
             batch_window = self._batch_window
             if self.probes is not None and max(1, self.qcfg.iterations) == 1:
                 batch_np["beff0"] = beff0.astype(np.float32)
-        with _x64(), warnings.catch_warnings():
+        with jax.enable_x64(), warnings.catch_warnings():
             # CPU jit declines buffer donation with a UserWarning; the
             # request is still the right thing on TPU/GPU.
             warnings.filterwarnings("ignore", message=".*[Dd]onat")
@@ -2780,7 +2779,7 @@ class FleetSim:
         if self._mig_rm is not None:
             plane0 += self._mig_rm[None]
 
-        with _x64():
+        with jax.enable_x64():
             out = _ctrl_exec(
                 self._device_tables(),
                 {k: jnp.asarray(v) for k, v in chunks.items()},

@@ -24,9 +24,9 @@ under hypothesis when it is available (heavy example counts ride the
 ``slow`` nightly tier).  The end-to-end pins run the fast 8x12 world at
 fixed seeds.
 """
+import jax
 import numpy as np
 import pytest
-from jax.experimental import enable_x64
 
 try:
     from hypothesis import given, settings
@@ -70,7 +70,7 @@ def check_law_contract(sp, b_max, b_hi, window, w, wd, c):
     we, beff = effective_work_np(w, wd, c, table, cfg.b_cap, window)
     # Traced form agrees with the host form (window pre-applied); the
     # fused kernel always evaluates these planes under x64.
-    with enable_x64():
+    with jax.enable_x64():
         we_j, beff_j = batched_effective_work(
             w, wd, np.asarray(windowed_counts_jnp(c, window)), table,
             float(cfg.b_cap))
@@ -94,7 +94,7 @@ def check_bcap1_identity(sp, w, wd, c):
     we, beff = effective_work_np(w, wd, c, table, cfg.b_cap)
     assert np.array_equal(we, w)                     # bitwise
     assert np.all(beff == 1.0)
-    with enable_x64():
+    with jax.enable_x64():
         we_j, _ = batched_effective_work(w, wd, c, table, 1.0)
     assert np.array_equal(np.asarray(we_j), w)
 
@@ -102,7 +102,7 @@ def check_bcap1_identity(sp, w, wd, c):
 def check_windowed_counts(cnt, window):
     c = np.asarray(cnt)
     out = windowed_counts(c, window)
-    with enable_x64():
+    with jax.enable_x64():
         out_j = np.asarray(windowed_counts_jnp(c, window))
     np.testing.assert_allclose(out_j, out, rtol=1e-12)
     assert np.all(out >= c - 1e-12)                  # inclusive of own bin
@@ -145,11 +145,17 @@ def test_law_contracts_seeded():
 
 
 if HAS_HYPOTHESIS:
+    def _floats(lo, hi):
+        # XLA flushes subnormals to zero, so the traced forms cannot
+        # match the host on them; work and counts are never that small.
+        return st.floats(min_value=lo, max_value=hi, allow_nan=False,
+                         allow_subnormal=False)
+
     speedups = st.lists(
-        st.floats(min_value=0.25, max_value=16.0, allow_nan=False),
+        _floats(0.25, 16.0),
         min_size=1, max_size=12)
     counts = st.lists(
-        st.floats(min_value=0.0, max_value=40.0, allow_nan=False),
+        _floats(0.0, 40.0),
         min_size=1, max_size=24)
 
     FAST = dict(max_examples=60, deadline=None)
@@ -157,15 +163,16 @@ if HAS_HYPOTHESIS:
 
     def _draw_planes(data, n):
         w = np.asarray(data.draw(st.lists(
-            st.floats(min_value=0.0, max_value=50.0, allow_nan=False),
+            _floats(0.0, 50.0),
             min_size=n, max_size=n)))
         f = np.asarray(data.draw(st.lists(
-            st.floats(min_value=0.0, max_value=1.0, allow_nan=False),
+            _floats(0.0, 1.0),
             min_size=n, max_size=n)))
         c = np.asarray(data.draw(st.lists(
-            st.floats(min_value=0.0, max_value=40.0, allow_nan=False),
+            _floats(0.0, 40.0),
             min_size=n, max_size=n)))
-        return w, w * f, c
+        wd = w * f
+        return w, np.where(wd < np.finfo(wd.dtype).tiny, 0.0, wd), c
 
     def _draw_law_case(data):
         sp = data.draw(speedups)

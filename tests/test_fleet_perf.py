@@ -5,6 +5,7 @@ admission regime), run vs run_many consistency, and Pallas deposit-kernel
 parity with the scatter-add reference in interpret mode."""
 import dataclasses
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -199,7 +200,7 @@ def test_deposit_kernel_float64_interpret():
     from repro.kernels.ops import deposit
     from repro.kernels.ref import deposit_ref
     rng = np.random.default_rng(1)
-    with queueing._x64():
+    with jax.enable_x64():
         rows = jnp.asarray(rng.integers(0, 11, 500).astype(np.int32))
         cols = jnp.asarray(rng.integers(0, 97, 500).astype(np.int32))
         vals = jnp.asarray(rng.random(500))
@@ -219,7 +220,7 @@ def test_deposit_segments_bitwise_vs_ref():
     from repro.kernels.ops import deposit_segments
     from repro.kernels.ref import deposit_ref
     rng = np.random.default_rng(2)
-    with queueing._x64():
+    with jax.enable_x64():
         for n_rows, n_cols, n in [(17, 300, 1000), (144, 2568, 4096),
                                   (8, 128, 7), (3, 5, 0)]:
             rows = jnp.asarray(rng.integers(0, n_rows, n).astype(np.int32))

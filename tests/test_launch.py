@@ -99,12 +99,13 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import json
 import jax, jax.numpy as jnp
 import numpy as np
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 from repro.configs import smoke_config
 from repro.models import Parallel, init_params, loss_fn, random_batch
 from repro.distributed.sharding import ShardingRules
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = jax.make_mesh((2, 4), ("data", "model"),
+                     axis_types=(AxisType.Auto, AxisType.Auto))
 cfg = smoke_config("deepseek-moe-16b")   # 8 experts / 4 = 2 per device
 par = Parallel(mesh=mesh)
 rules = ShardingRules(cfg, mesh)
@@ -162,3 +163,68 @@ def test_dryrun_cli_end_to_end(tmp_path):
     assert r["memory_s"] > 0 and r["dominant"] in (
         "compute", "memory", "collective")
     assert out["memory_analysis"]["argument_size_in_bytes"] > 0
+
+
+# --------------------------------------------------------------------- #
+# Serving: placement, peaks, compile cache
+# --------------------------------------------------------------------- #
+
+
+def test_placement_permutes_expert_stacks_in_place():
+    """plan_and_apply_placement == apply_placement unit by unit, and the
+    placed model computes the same logits (routing is permuted with the
+    experts)."""
+    import numpy as np
+
+    from repro.launch.serve import (calibrate_router_stats,
+                                    plan_and_apply_placement)
+    from repro.models import forward, init_params, random_batch
+    from repro.models.moe import apply_placement
+
+    cfg = smoke_config("llama-moe-3.5b")
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    batch = {"tokens": random_batch(cfg, 2, 16, seed=3)["tokens"]}
+    counts = calibrate_router_stats(cfg, params, batch)
+    logits0, _ = forward(cfg, params, batch)
+    ffn0 = jax.tree.map(np.asarray, params["units"]["b0"]["ffn"])
+
+    placed, plans, _ = plan_and_apply_placement(cfg, params, counts)
+    ffn = placed["units"]["b0"]["ffn"]
+    for u, plan in enumerate(plans):
+        want = apply_placement(jax.tree.map(lambda a: a[u], ffn0),
+                               plan.expert_perm)
+        for k, w in want.items():
+            np.testing.assert_array_equal(np.asarray(ffn[k][u]),
+                                          np.asarray(w))
+    logits1, _ = forward(cfg, placed, batch)
+    np.testing.assert_allclose(np.asarray(logits1), np.asarray(logits0),
+                               atol=1e-5, rtol=1e-5)
+
+
+def test_device_peaks_keyed_by_kind():
+    from repro.launch.roofline import device_peaks
+    v5e = device_peaks("TPU v5 lite")
+    assert (v5e.flops, v5e.hbm_bw) == (197e12, 819e9)
+    with pytest.raises(ValueError, match="no published peaks"):
+        device_peaks("cpu")
+
+
+@pytest.mark.parametrize("env", ["/some/shared/cache", None])
+def test_compile_cache_dir(monkeypatch, env):
+    """JAX_COMPILATION_CACHE_DIR wins and is left to jax; otherwise the
+    cache sits at a fixed path inside the checkout."""
+    from repro.launch.cache import DEFAULT_DIR, REPO_ROOT, setup_compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        if env is None:
+            monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+            assert setup_compile_cache() == str(DEFAULT_DIR)
+            assert jax.config.jax_compilation_cache_dir == str(DEFAULT_DIR)
+            assert DEFAULT_DIR.parent == REPO_ROOT
+            assert (REPO_ROOT / "pyproject.toml").exists()
+        else:
+            monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env)
+            assert setup_compile_cache() == env
+            assert jax.config.jax_compilation_cache_dir == before
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
