@@ -104,7 +104,8 @@ def make_serve_step(cfg: ModelConfig, par: Parallel):
     def serve_step(params, cache, tokens, pos, embeds):
         logits, cache = decode_step(cfg, params, cache, tokens, pos, par=par,
                                     embeds=embeds)
-        next_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)[:, None]
+        with jax.named_scope("head"):
+            next_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)[:, None]
         return next_tok, logits, cache
 
     return serve_step

@@ -36,6 +36,7 @@ def attn_init(key, cfg: ModelConfig, dtype) -> dict:
     return p
 
 
+@jax.named_scope("attn.qkv")
 def _project_qkv(cfg: ModelConfig, params, x, positions, compute_dtype):
     """x: (B, S, d) -> q (B,S,Hq,hd), k/v (B,S,Hkv,hd), with RoPE."""
     b, s, _ = x.shape
@@ -67,6 +68,7 @@ def _pick_chunk(s: int, target: int) -> int:
     return c
 
 
+@jax.named_scope("attn.core")
 def flash_attention(
     cfg: ModelConfig,
     q: jnp.ndarray,          # (B, S, Hq, hd)
@@ -278,7 +280,8 @@ def attention_forward(
     q, k, v = _project_qkv(cfg, params, x, positions, compute_dtype)
     out = flash_attention(cfg, q, k, v, positions, positions)
     b, s = x.shape[:2]
-    return out.reshape(b, s, cfg.q_dim) @ params["w_o"].astype(compute_dtype)
+    with jax.named_scope("attn.out"):
+        return out.reshape(b, s, cfg.q_dim) @ params["w_o"].astype(compute_dtype)
 
 
 def attention_prefill(
@@ -288,16 +291,18 @@ def attention_prefill(
     """Prefill: run causal attention AND write K/V into the cache at [0, S)."""
     q, k, v = _project_qkv(cfg, params, x, positions, compute_dtype)
     out = flash_attention(cfg, q, k, v, positions, positions)
-    new_cache = {
-        "k": jax.lax.dynamic_update_slice(
-            cache["k"], k.astype(cache["k"].dtype), (0, 0, 0, 0)
-        ),
-        "v": jax.lax.dynamic_update_slice(
-            cache["v"], v.astype(cache["v"].dtype), (0, 0, 0, 0)
-        ),
-    }
+    with jax.named_scope("attn.kv_write"):
+        new_cache = {
+            "k": jax.lax.dynamic_update_slice(
+                cache["k"], k.astype(cache["k"].dtype), (0, 0, 0, 0)
+            ),
+            "v": jax.lax.dynamic_update_slice(
+                cache["v"], v.astype(cache["v"].dtype), (0, 0, 0, 0)
+            ),
+        }
     b, s = x.shape[:2]
-    y = out.reshape(b, s, cfg.q_dim) @ params["w_o"].astype(compute_dtype)
+    with jax.named_scope("attn.out"):
+        y = out.reshape(b, s, cfg.q_dim) @ params["w_o"].astype(compute_dtype)
     return y, new_cache
 
 
@@ -321,28 +326,38 @@ def attention_decode(
     q, k, v = _project_qkv(cfg, params, x, positions, compute_dtype)
 
     # Scatter the new K/V row at each batch element's position.
-    batch_idx = jnp.arange(b)
-    ck = cache["k"].at[batch_idx, pos].set(k[:, 0].astype(cache["k"].dtype))
-    cv = cache["v"].at[batch_idx, pos].set(v[:, 0].astype(cache["v"].dtype))
+    with jax.named_scope("attn.kv_write"):
+        batch_idx = jnp.arange(b)
+        ck = cache["k"].at[batch_idx, pos].set(
+            k[:, 0].astype(cache["k"].dtype))
+        cv = cache["v"].at[batch_idx, pos].set(
+            v[:, 0].astype(cache["v"].dtype))
 
-    s_max = ck.shape[1]
-    hkv, g, hd = cfg.n_kv_heads, cfg.n_heads // cfg.n_kv_heads, cfg.head_dim
-    qg = q.reshape(b, hkv, g, hd)
+    hkv, g = cfg.n_kv_heads, cfg.n_heads // cfg.n_kv_heads
+    qg = q.reshape(b, hkv, g, cfg.head_dim)
+    out = _decode_core(cfg, qg, ck, cv, pos, compute_dtype)
+    with jax.named_scope("attn.out"):
+        y = out.reshape(b, 1, cfg.q_dim).astype(compute_dtype) \
+            @ params["w_o"].astype(compute_dtype)
+    return y, {"k": ck, "v": cv}
+
+
+@jax.named_scope("attn.core")
+def _decode_core(cfg: ModelConfig, qg, ck, cv, pos, compute_dtype):
+    """One query per sequence over its cache: (B, Hkv, G, hd) -> same."""
+    s_max, hd = ck.shape[1], cfg.head_dim
     if cfg.use_pallas_decode and cfg.sliding_window == 0 \
             and cfg.attn_logit_softcap == 0:
         # Pallas flash-decode kernel: one HBM pass over the cache.  (The
         # cache transpose to (B,Hkv,S,hd) is layout-only; a production
         # deployment keeps the cache in kernel layout.)
         from repro.kernels.ops import decode_attention as _pallas_decode
-        out = _pallas_decode(
+        return _pallas_decode(
             qg.astype(compute_dtype),
             ck.transpose(0, 2, 1, 3).astype(compute_dtype),
             cv.transpose(0, 2, 1, 3).astype(compute_dtype),
             pos,
         )
-        y = out.reshape(b, 1, cfg.q_dim).astype(compute_dtype) \
-            @ params["w_o"].astype(compute_dtype)
-        return y, {"k": ck, "v": cv}
     kt = ck.astype(compute_dtype)
     vt = cv.astype(compute_dtype)
     sco = jnp.einsum("bngd,bsnd->bngs", qg, kt,
@@ -354,8 +369,5 @@ def attention_decode(
         mask = mask & ((pos[:, None] - kv_pos) < cfg.sliding_window)
     sco = jnp.where(mask[:, None, None, :], sco, NEG_INF)
     p = jax.nn.softmax(sco, axis=-1)
-    out = jnp.einsum("bngs,bsnd->bngd", p.astype(compute_dtype), vt,
-                     preferred_element_type=jnp.float32)
-    y = out.reshape(b, 1, cfg.q_dim).astype(compute_dtype) \
-        @ params["w_o"].astype(compute_dtype)
-    return y, {"k": ck, "v": cv}
+    return jnp.einsum("bngs,bsnd->bngd", p.astype(compute_dtype), vt,
+                      preferred_element_type=jnp.float32)

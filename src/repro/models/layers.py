@@ -5,6 +5,16 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+#: ``jax.named_scope`` names of the model's stages, as they appear in each
+#: compiled op's ``op_name`` metadata (and so in a profiler trace).  They
+#: are metadata only: the optimized program is the same without them.
+STAGES = (
+    "embed", "norm",
+    "attn.qkv", "attn.kv_write", "attn.core", "attn.out",
+    "moe.router", "moe.dispatch", "moe.experts", "moe.combine", "moe.shared",
+    "ffn.dense", "head", "layers",
+)
+
 
 def dtype_of(name: str):
     return {"float32": jnp.float32, "bfloat16": jnp.bfloat16,
@@ -34,6 +44,7 @@ def rmsnorm_init(d: int, dtype) -> dict:
     return {"scale": jnp.ones((d,), dtype=dtype)}
 
 
+@jax.named_scope("norm")
 def rmsnorm(params: dict, x: jnp.ndarray, eps: float = 1e-5) -> jnp.ndarray:
     orig = x.dtype
     x = x.astype(jnp.float32)
@@ -81,6 +92,7 @@ def ffn_init(key, d_model: int, d_ff: int, n_layers: int, dtype) -> dict:
     }
 
 
+@jax.named_scope("ffn.dense")
 def ffn_apply(params: dict, x: jnp.ndarray, compute_dtype) -> jnp.ndarray:
     x = x.astype(compute_dtype)
     gate = jax.nn.silu(x @ params["w_gate"].astype(compute_dtype))
@@ -97,10 +109,12 @@ def embedding_init(key, vocab: int, d_model: int, dtype) -> jnp.ndarray:
     return normal_init(key, (vocab, d_model), dtype)
 
 
+@jax.named_scope("embed")
 def embed(table: jnp.ndarray, tokens: jnp.ndarray, compute_dtype) -> jnp.ndarray:
     return jnp.take(table, tokens, axis=0).astype(compute_dtype)
 
 
+@jax.named_scope("head")
 def lm_head(table_or_w: jnp.ndarray, x: jnp.ndarray, tied: bool) -> jnp.ndarray:
     w = table_or_w.astype(x.dtype)
     return x @ (w.T if tied else w)

@@ -262,8 +262,9 @@ def forward(cfg: ModelConfig, params: dict, batch: dict,
     body = unit_step
     if cfg.remat == "unit":
         body = jax.checkpoint(unit_step, prevent_cse=False)
-    (x, aux_total), counts = jax.lax.scan(body, (x, aux_total),
-                                          params["units"])
+    with jax.named_scope("layers"):
+        (x, aux_total), counts = jax.lax.scan(body, (x, aux_total),
+                                              params["units"])
     x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
     table = params["embed"] if cfg.tie_embeddings else params["head"]
     logits = lm_head(table, x, cfg.tie_embeddings)
@@ -350,9 +351,10 @@ def decode_step(cfg: ModelConfig, params: dict, cache: dict,
                                   unit_cache, "decode")
         return x, nc
 
-    x, new_unit_caches = jax.lax.scan(
-        unit_step, x, (params["units"], cache["units"])
-    )
+    with jax.named_scope("layers"):
+        x, new_unit_caches = jax.lax.scan(
+            unit_step, x, (params["units"], cache["units"])
+        )
     new_cache["units"] = new_unit_caches
     x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
     table = params["embed"] if cfg.tie_embeddings else params["head"]
@@ -385,9 +387,10 @@ def prefill(cfg: ModelConfig, params: dict, batch: dict, max_len: int,
                                   unit_cache, "prefill")
         return x, nc
 
-    x, new_unit_caches = jax.lax.scan(
-        unit_step, x, (params["units"], cache["units"])
-    )
+    with jax.named_scope("layers"):
+        x, new_unit_caches = jax.lax.scan(
+            unit_step, x, (params["units"], cache["units"])
+        )
     new_cache["units"] = new_unit_caches
     x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
     table = params["embed"] if cfg.tie_embeddings else params["head"]

@@ -136,6 +136,7 @@ def moe_init(key, cfg: ModelConfig, dtype) -> dict:
 # --------------------------------------------------------------------- #
 
 
+@jax.named_scope("moe.router")
 def route(cfg: ModelConfig, router_w: jnp.ndarray, x: jnp.ndarray):
     """x: (T, d) -> (weights (T,K), idx (T,K) int32, aux dict)."""
     logits = x.astype(jnp.float32) @ router_w.astype(jnp.float32)   # (T, E)
@@ -200,6 +201,7 @@ def dispatch_indices(idx: jnp.ndarray, n_experts: int, cap: int):
     return slot_token, slot_valid, copy_slot, copy_kept
 
 
+@jax.named_scope("moe.experts")
 def expert_ffn(params: dict, xs: jnp.ndarray, compute_dtype) -> jnp.ndarray:
     """Batched SwiGLU over expert buckets.  xs: (E, C, d) -> (E, C, d)."""
     wg = params["w_gate"].astype(compute_dtype)
@@ -210,6 +212,7 @@ def expert_ffn(params: dict, xs: jnp.ndarray, compute_dtype) -> jnp.ndarray:
     return jnp.einsum("ecf,efd->ecd", gate * up, wd)
 
 
+@jax.named_scope("moe.shared")
 def _shared_ffn(params: dict, x: jnp.ndarray, compute_dtype) -> jnp.ndarray:
     g = jax.nn.silu(x @ params["w_gate"].astype(compute_dtype))
     u = x @ params["w_up"].astype(compute_dtype)
@@ -225,9 +228,28 @@ def _plan(cfg: ModelConfig, idx: jnp.ndarray, t: int):
             capacity(cfg, t, sl.e_pad), sl.frag)
 
 
-def _combine(gathered: jnp.ndarray, weights: jnp.ndarray, t: int, k: int,
-             frag: int, compute_dtype) -> jnp.ndarray:
-    """(T*K*frag, d) copy outputs -> (T, d): sum fragments, weight top-K."""
+@jax.named_scope("moe.dispatch")
+def _dispatch(xt: jnp.ndarray, idx: jnp.ndarray, n_buckets: int, cap: int,
+              copies_per_token: int, compute_dtype):
+    """Gather each token copy into its capacity-padded bucket.
+
+    Returns the (n_buckets * cap, d) buckets and the plan ``(copy_slot,
+    copy_kept)`` that ``_combine`` brings the outputs home with."""
+    slot_token, slot_valid, copy_slot, copy_kept = dispatch_indices(
+        idx, n_buckets, cap
+    )
+    copies = jnp.repeat(xt, copies_per_token, axis=0)
+    buckets = copies[slot_token] * slot_valid[:, None].astype(compute_dtype)
+    return buckets, (copy_slot, copy_kept)
+
+
+@jax.named_scope("moe.combine")
+def _combine(flat_out: jnp.ndarray, plan, weights: jnp.ndarray, t: int,
+             k: int, frag: int, compute_dtype) -> jnp.ndarray:
+    """(n_buckets * cap, d) bucket outputs -> (T, d): gather each copy's
+    output, sum fragments, weight top-K."""
+    copy_slot, copy_kept = plan
+    gathered = flat_out[copy_slot] * copy_kept[:, None].astype(compute_dtype)
     per_copy = gathered.reshape(t, k, frag, -1).sum(axis=2)
     return jnp.einsum("tkd,tk->td", per_copy, weights.astype(compute_dtype))
 
@@ -240,16 +262,11 @@ def moe_apply_local(cfg: ModelConfig, params: dict, x: jnp.ndarray,
     xt = x.reshape(t, d).astype(compute_dtype)
     weights, idx, aux = route(cfg, params["router"], xt)
     v_idx, n_b, cap, frag = _plan(cfg, idx, t)
-    slot_token, slot_valid, copy_slot, copy_kept = dispatch_indices(
-        v_idx, n_b, cap
-    )
-    copies = jnp.repeat(xt, cfg.top_k * frag, axis=0)         # (T*K*frag, d)
-    buckets = copies[slot_token] * slot_valid[:, None].astype(compute_dtype)
-    buckets = buckets.reshape(n_b, cap, d)
-    outs = expert_ffn(params, buckets, compute_dtype)
-    flat_out = outs.reshape(n_b * cap, d)
-    gathered = flat_out[copy_slot] * copy_kept[:, None].astype(compute_dtype)
-    y = _combine(gathered, weights, t, cfg.top_k, frag, compute_dtype)
+    buckets, plan = _dispatch(xt, v_idx, n_b, cap, cfg.top_k * frag,
+                              compute_dtype)
+    outs = expert_ffn(params, buckets.reshape(n_b, cap, d), compute_dtype)
+    y = _combine(outs.reshape(n_b * cap, d), plan, weights, t, cfg.top_k,
+                 frag, compute_dtype)
     if cfg.n_shared_experts > 0:
         y = y + _shared_ffn(params["shared"], xt, compute_dtype)
     return y.reshape(b, s, d), aux
@@ -289,11 +306,8 @@ def moe_apply_ep(cfg: ModelConfig, params: dict, x_local: jnp.ndarray,
     if n_b != loc * n_dev:
         raise ValueError(f"bucket count {n_b} != {loc}x{n_dev} local stacks")
 
-    slot_token, slot_valid, copy_slot, copy_kept = dispatch_indices(
-        v_idx, n_b, cap
-    )
-    copies = jnp.repeat(xt, cfg.top_k * frag, axis=0)
-    buckets = copies[slot_token] * slot_valid[:, None].astype(compute_dtype)
+    buckets, plan = _dispatch(xt, v_idx, n_b, cap, cfg.top_k * frag,
+                              compute_dtype)
     buckets = buckets.reshape(n_dev, loc, cap, d)             # dest-device major
 
     # exchange buckets: after a2a, axis 0 indexes the *source* device.
@@ -305,10 +319,8 @@ def moe_apply_ep(cfg: ModelConfig, params: dict, x_local: jnp.ndarray,
     back = outs.reshape(loc, n_dev, cap, d).transpose(1, 0, 2, 3)
     home = jax.lax.all_to_all(back, axis_name, split_axis=0, concat_axis=0,
                               tiled=False)
-    flat_out = home.reshape(n_b * cap, d)
-
-    gathered = flat_out[copy_slot] * copy_kept[:, None].astype(compute_dtype)
-    y = _combine(gathered, weights, t, cfg.top_k, frag, compute_dtype)
+    y = _combine(home.reshape(n_b * cap, d), plan, weights, t, cfg.top_k,
+                 frag, compute_dtype)
     if cfg.n_shared_experts > 0:
         y = y + _shared_ffn(params["shared"], xt, compute_dtype)
     return y.reshape(b, s, d), aux
@@ -340,19 +352,15 @@ def moe_apply_ep_replicated(cfg: ModelConfig, params: dict,
     # bucket (local id loc) whose output is forced to zero.
     is_mine = (v_idx // loc) == my
     local_idx = jnp.where(is_mine, v_idx - my * loc, loc)
-    slot_token, slot_valid, copy_slot, copy_kept = dispatch_indices(
-        local_idx, loc + 1, cap
-    )
-    copies = jnp.repeat(xt, cfg.top_k * frag, axis=0)
-    buckets = copies[slot_token] * slot_valid[:, None].astype(compute_dtype)
+    buckets, plan = _dispatch(xt, local_idx, loc + 1, cap, cfg.top_k * frag,
+                              compute_dtype)
     buckets = buckets.reshape(loc + 1, cap, d)
     outs = expert_ffn(params, buckets[:loc], compute_dtype)
     outs = jnp.concatenate(
         [outs, jnp.zeros((1, cap, d), outs.dtype)], axis=0
     )                                                   # zero trash bucket
-    flat_out = outs.reshape((loc + 1) * cap, d)
-    gathered = flat_out[copy_slot] * copy_kept[:, None].astype(compute_dtype)
-    y = _combine(gathered, weights, t, cfg.top_k, frag, compute_dtype)
+    y = _combine(outs.reshape((loc + 1) * cap, d), plan, weights, t,
+                 cfg.top_k, frag, compute_dtype)
     y = jax.lax.psum(y, axis_name)
     if cfg.n_shared_experts > 0:
         y = y + _shared_ffn(params["shared"], xt, compute_dtype)  # replicated
