@@ -313,33 +313,54 @@ def init_kv_cache(cfg: ModelConfig, batch: int, max_len: int, dtype) -> dict:
 
 def attention_decode(
     cfg: ModelConfig, params: dict, x: jnp.ndarray, pos: jnp.ndarray,
-    cache: dict, compute_dtype,
+    cache: dict, compute_dtype, unit: jnp.ndarray | None = None,
 ) -> tuple[jnp.ndarray, dict]:
     """One-token decode: x (B, 1, d), pos (B,) current position.
 
-    Writes k/v at ``pos``, attends over cache[0..pos].  This is the jnp
-    reference path; the Pallas ``decode_attn`` kernel implements the same
-    contract for TPU.
+    ``cache`` holds this layer's k/v (B, S, Hkv, hd), or with ``unit`` the
+    stack over the layer scan's units (U, B, S, Hkv, hd) and this layer's
+    index in it.  Writes the B new k/v rows at ``[unit,] b, pos[b]`` and
+    attends over this layer's cache[0..pos]; the cache comes back with only
+    those rows changed.  This is the jnp reference path; the Pallas
+    ``decode_attn`` kernel implements the same contract for TPU.
     """
     b = x.shape[0]
     positions = pos[:, None]                                   # (B, 1)
     q, k, v = _project_qkv(cfg, params, x, positions, compute_dtype)
 
-    # Scatter the new K/V row at each batch element's position.
     with jax.named_scope("attn.kv_write"):
-        batch_idx = jnp.arange(b)
-        ck = cache["k"].at[batch_idx, pos].set(
-            k[:, 0].astype(cache["k"].dtype))
-        cv = cache["v"].at[batch_idx, pos].set(
-            v[:, 0].astype(cache["v"].dtype))
+        ck = _write_rows(cache["k"], k, pos, unit)
+        cv = _write_rows(cache["v"], v, pos, unit)
+    lk, lv = ck, cv
+    if unit is not None:
+        lk = jax.lax.dynamic_index_in_dim(ck, unit, keepdims=False)
+        lv = jax.lax.dynamic_index_in_dim(cv, unit, keepdims=False)
 
     hkv, g = cfg.n_kv_heads, cfg.n_heads // cfg.n_kv_heads
     qg = q.reshape(b, hkv, g, cfg.head_dim)
-    out = _decode_core(cfg, qg, ck, cv, pos, compute_dtype)
+    out = _decode_core(cfg, qg, lk, lv, pos, compute_dtype)
     with jax.named_scope("attn.out"):
         y = out.reshape(b, 1, cfg.q_dim).astype(compute_dtype) \
             @ params["w_o"].astype(compute_dtype)
     return y, {"k": ck, "v": cv}
+
+
+def _write_rows(cache: jnp.ndarray, rows: jnp.ndarray, pos: jnp.ndarray,
+                unit) -> jnp.ndarray:
+    """Write row b of ``rows`` (B, 1, Hkv, hd) at ``[unit,] b, pos[b]``.
+
+    One dynamic-update-slice per sequence: it is done in place whatever
+    layout the compiler gives the cache (a scatter asks for ``hd`` minor,
+    and where the cache is kept with ``S`` minor the compiler copies the
+    whole cache to meet it).
+    """
+    rows = rows.astype(cache.dtype)
+    lead = () if unit is None else (unit,)
+    for i in range(rows.shape[0]):
+        row = rows[i].reshape((1,) * (cache.ndim - 2) + rows.shape[2:])
+        cache = jax.lax.dynamic_update_slice(cache, row,
+                                             (*lead, i, pos[i], 0, 0))
+    return cache
 
 
 @jax.named_scope("attn.core")

@@ -4,7 +4,8 @@ The layer stack is a ``lax.scan`` over repeating pattern units (HLO size
 independent of depth).  Each unit applies its pattern of
 (mixer, ffn) blocks; mixers are attention / mamba / mlstm / slstm, FFNs
 are dense SwiGLU or MoE.  Decode carries a per-unit cache pytree (KV cache
-for attention, recurrent state for SSM blocks) stacked along the unit axis.
+for attention, recurrent state for SSM blocks) stacked along the unit axis
+through the scan; each block writes only its own rows of it.
 
 Parallelism: activations are batch-sharded; tensor parallelism comes from
 weight sharding (pjit propagation); expert parallelism uses the explicit
@@ -127,23 +128,42 @@ def init_params(cfg: ModelConfig, key) -> dict:
 # ===================================================================== #
 
 
-def _apply_mixer(cfg, spec, bp, x, positions, par, cdt, cache, mode):
-    """Returns (y, new_cache)."""
+def _apply_mixer(cfg, spec, bp, x, positions, par, cdt, cache, mode,
+                 unit=None):
+    """Returns (y, new_cache).
+
+    With ``unit`` (decode inside the layer scan) ``cache`` is the stack
+    over units and ``unit`` this block's index in it: attention writes its
+    new K/V rows into the stack, a recurrent block its whole state at
+    ``unit``.  The stack comes back updated in place.
+    """
     if spec.mixer == "attn":
         if mode == "train":
             return attn.attention_forward(cfg, bp["mixer"], x, positions, cdt), None
         if mode == "prefill":
             return attn.attention_prefill(cfg, bp["mixer"], x, positions, cache, cdt)
-        return attn.attention_decode(cfg, bp["mixer"], x, positions, cache, cdt)
+        return attn.attention_decode(cfg, bp["mixer"], x, positions, cache, cdt,
+                                     unit)
     if spec.mixer in ("mamba", "mlstm"):
         fwd, dec = {"mamba": (ssm.mamba_forward, ssm.mamba_decode),
                     "mlstm": (ssm.mlstm_forward, ssm.mlstm_decode)}[spec.mixer]
         if mode == "train":
             return fwd(cfg, bp["mixer"], x, cdt, par), None
-        return dec(cfg, bp["mixer"], x, cache, cdt, par)
-    if mode == "train":
-        return ssm.slstm_forward(cfg, bp["mixer"], x, cdt), None
-    return ssm.slstm_decode(cfg, bp["mixer"], x, cache, cdt)
+        step = functools.partial(dec, cfg, bp["mixer"], x, compute_dtype=cdt,
+                                 par=par)
+    else:
+        if mode == "train":
+            return ssm.slstm_forward(cfg, bp["mixer"], x, cdt), None
+        step = functools.partial(ssm.slstm_decode, cfg, bp["mixer"], x,
+                                 compute_dtype=cdt)
+    if unit is None:
+        return step(cache)
+    y, state = step(jax.tree.map(
+        lambda a: jax.lax.dynamic_index_in_dim(a, unit, keepdims=False), cache))
+    return y, jax.tree.map(
+        lambda a, s: jax.lax.dynamic_update_index_in_dim(a, s.astype(a.dtype),
+                                                         unit, 0),
+        cache, state)
 
 
 def _apply_moe(cfg, bp_ffn, x, par: Parallel, cdt):
@@ -185,10 +205,12 @@ def _apply_moe(cfg, bp_ffn, x, par: Parallel, cdt):
     return sharded(bp_ffn, x)
 
 
-def _apply_block(cfg, spec, bp, x, positions, par, cdt, cache, mode):
+def _apply_block(cfg, spec, bp, x, positions, par, cdt, cache, mode,
+                 unit=None):
     aux = None
     h = rmsnorm(bp["norm1"], x, cfg.norm_eps)
-    y, new_cache = _apply_mixer(cfg, spec, bp, h, positions, par, cdt, cache, mode)
+    y, new_cache = _apply_mixer(cfg, spec, bp, h, positions, par, cdt, cache,
+                                mode, unit)
     x = x + y
     if spec.ffn != "none":
         h = rmsnorm(bp["norm2"], x, cfg.norm_eps)
@@ -200,14 +222,16 @@ def _apply_block(cfg, spec, bp, x, positions, par, cdt, cache, mode):
     return x, new_cache, aux
 
 
-def _apply_unit(cfg, unit_params, x, positions, par, cdt, unit_cache, mode):
+def _apply_unit(cfg, unit_params, x, positions, par, cdt, unit_cache, mode,
+                unit=None):
     new_caches = {}
     aux_sum = jnp.zeros((), jnp.float32)
     counts = jnp.zeros((max(cfg.n_experts, 1),), jnp.float32)
     for i, spec in enumerate(cfg.pattern):
         cache_i = None if unit_cache is None else unit_cache.get(f"b{i}")
         x, nc, aux = _apply_block(
-            cfg, spec, unit_params[f"b{i}"], x, positions, par, cdt, cache_i, mode
+            cfg, spec, unit_params[f"b{i}"], x, positions, par, cdt, cache_i,
+            mode, unit
         )
         if nc is not None:
             new_caches[f"b{i}"] = nc
@@ -331,6 +355,13 @@ def decode_step(cfg: ModelConfig, params: dict, cache: dict,
 
     tokens: (B, 1) int32 (or ``embeds`` (B, 1, d) for stub frontends);
     pos: (B,) positions of these tokens.  Returns (logits (B, V), cache').
+
+    The layer scan runs over (unit params, unit index) and carries
+    ``(x, cache["units"])``: each attention block writes its B new K/V rows
+    into the carried stack at its unit index and attends over that layer
+    of it; each recurrent block writes its whole state at the index.  The
+    returned stack is the final carry, so a donated cache is updated in
+    place rather than rebuilt layer by layer.
     """
     cdt = dtype_of(cfg.compute_dtype)
     if embeds is not None:
@@ -345,17 +376,18 @@ def decode_step(cfg: ModelConfig, params: dict, cache: dict,
         )
         new_cache["first"] = fc
 
-    def unit_step(x, xs):
-        unit_params, unit_cache = xs
-        x, nc, _, _ = _apply_unit(cfg, unit_params, x, pos, par, cdt,
-                                  unit_cache, "decode")
-        return x, nc
+    def unit_step(carry, xs):
+        x, caches = carry
+        unit_params, unit = xs
+        x, caches, _, _ = _apply_unit(cfg, unit_params, x, pos, par, cdt,
+                                      caches, "decode", unit)
+        return (x, caches), None
 
+    units = jnp.arange(n_scan_units(cfg), dtype=jnp.int32)
     with jax.named_scope("layers"):
-        x, new_unit_caches = jax.lax.scan(
-            unit_step, x, (params["units"], cache["units"])
+        (x, new_cache["units"]), _ = jax.lax.scan(
+            unit_step, (x, cache["units"]), (params["units"], units)
         )
-    new_cache["units"] = new_unit_caches
     x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
     table = params["embed"] if cfg.tie_embeddings else params["head"]
     logits = lm_head(table, x, cfg.tie_embeddings)

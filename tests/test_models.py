@@ -195,6 +195,45 @@ def test_decode_matches_forward(case):
                                atol=2e-4, rtol=2e-4)
 
 
+TEACHER_CASES = {
+    "moe": ARCH_CASES["moe"],           # one attention row written a layer
+    "hybrid": ARCH_CASES["hybrid"],     # + a whole mamba state at the unit
+    "first_layer_dense": dict(ARCH_CASES["moe"], n_layers=3,
+                              first_layer_dense=True),  # its own buffer
+}
+
+
+@pytest.mark.parametrize("case", list(TEACHER_CASES))
+def test_decode_steps_match_forward_ragged(case):
+    """Several teacher-forced decode steps, each sequence at its own
+    position, give the full forward's logits at those positions."""
+    cfg = tiny_cfg(**TEACHER_CASES[case])
+    params = jax.jit(lambda k: init_params(cfg, k))(jax.random.PRNGKey(0))
+    lens, n_steps = (5, 9, 7), 4
+    b, total = len(lens), max(lens) + n_steps
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (b, total), 0,
+                                cfg.vocab_size)
+    logits_full, _ = forward(cfg, params, {"tokens": tokens})
+    # Prefill each sequence alone at its own length, then batch the caches
+    # (batch is axis 1 of the stacked unit cache, axis 0 of the first's).
+    caches = [prefill(cfg, params, {"tokens": tokens[i:i + 1, :n]},
+                      max_len=total)[1] for i, n in enumerate(lens)]
+    cache = {"units": jax.tree.map(lambda *a: jnp.concatenate(a, axis=1),
+                                   *[c["units"] for c in caches])}
+    if cfg.first_layer_dense:
+        cache["first"] = jax.tree.map(lambda *a: jnp.concatenate(a, axis=0),
+                                      *[c["first"] for c in caches])
+    step = jax.jit(lambda c, t, p: decode_step(cfg, params, c, t, p))
+    rows = jnp.arange(b)
+    pos = jnp.asarray(lens, jnp.int32)
+    for _ in range(n_steps):
+        got, cache = step(cache, tokens[rows, pos][:, None], pos)
+        np.testing.assert_allclose(np.asarray(got),
+                                   np.asarray(logits_full[rows, pos]),
+                                   atol=2e-4, rtol=2e-4)
+        pos = pos + 1
+
+
 def test_training_step_reduces_loss():
     cfg = tiny_cfg()
     params = init_params(cfg, jax.random.PRNGKey(0))

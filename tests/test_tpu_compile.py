@@ -1,16 +1,22 @@
-"""The Pallas kernels compile for a TPU v5e at real widths.
+"""The Pallas kernels and the served decode step compile for a TPU v5e at
+real widths.
 
-Each test compiles a kernel for a described (not attached) v5e chip and
-checks that the Mosaic kernel made it into the program
+Each kernel test compiles a kernel for a described (not attached) v5e chip
+and checks that the Mosaic kernel made it into the program
 (``tpu_custom_call``).  Nothing runs, so this says nothing about
 results or times; it catches what interpret mode cannot: block shapes
 the TPU's tiling refuses, i64 block indices, kernels that need more
-fast memory than a chip has.
+fast memory than a chip has.  The decode step's test reads the layouts
+the TPU compiler chose: a copy of the whole stacked KV cache there is
+what the chip would run every step.
 
 The topology is described inside a fixture, never at import: only one
 process may load the TPU library, and every test worker imports this
 file.
 """
+import functools
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -19,6 +25,9 @@ from jax.sharding import SingleDeviceSharding
 from repro.kernels.decode_attn import decode_attention
 from repro.kernels.deposit import deposit
 from repro.kernels.moe_gmm import gmm
+from repro.launch.steps import make_serve_step
+from repro.models import (LayerSpec, ModelConfig, Parallel, init_cache,
+                          init_params)
 
 
 @pytest.fixture(scope="module")
@@ -86,3 +95,48 @@ def test_decode_attention_compiles_llama_moe(one_chip):
     kv = _struct((4, 32, 1024, 128), jnp.bfloat16, one_chip)
     pos = _struct((4,), jnp.int32, one_chip)
     _assert_mosaic(decode_attention, q, kv, kv, pos)
+
+
+# Decode widths of the benchmark's two serving cells, at a few layers: the
+# cache plumbing of the layer scan does not depend on depth.
+SERVE_WIDTHS = {
+    "granite-moe-3b-a800m": dict(
+        cfg=dict(d_model=1536, n_heads=24, n_kv_heads=8, head_dim=64,
+                 n_experts=40, top_k=8, d_ff=512, d_ff_expert=512,
+                 capacity_factor=5.0, vocab_size=49155, tie_embeddings=True),
+        batch=32, max_len=640),
+    "deepseek-moe-16b": dict(
+        cfg=dict(d_model=2048, n_heads=16, n_kv_heads=16, head_dim=128,
+                 n_experts=64, top_k=6, n_shared_experts=2, d_ff=1408,
+                 d_ff_expert=1408, capacity_factor=64 / 6, vocab_size=102400,
+                 first_layer_dense=True, first_dense_d_ff=10944),
+        batch=8, max_len=640),
+}
+
+
+@pytest.mark.parametrize("model", list(SERVE_WIDTHS))
+def test_serve_step_updates_stacked_cache_in_place(one_chip, model):
+    """The decode step with its cache donated: no op copies the whole
+    stacked K or V cache, so each step writes only the new rows."""
+    w = SERVE_WIDTHS[model]
+    cfg = ModelConfig(name=model, n_layers=3, pattern=(LayerSpec("attn", "moe"),),
+                      param_dtype="bfloat16", compute_dtype="bfloat16",
+                      **w["cfg"])
+    b, max_len = w["batch"], w["max_len"]
+    on_chip = functools.partial(jax.tree.map, lambda s: _struct(
+        s.shape, s.dtype, one_chip))
+    params = on_chip(jax.eval_shape(functools.partial(init_params, cfg),
+                                    jax.random.PRNGKey(0)))
+    cache = on_chip(jax.eval_shape(functools.partial(init_cache, cfg, b,
+                                                     max_len)))
+    tok = _struct((b, 1), jnp.int32, one_chip)
+    pos = _struct((b,), jnp.int32, one_chip)
+    hlo = jax.jit(make_serve_step(cfg, Parallel()), donate_argnums=(1,)) \
+        .lower(params, cache, tok, pos, None).compile().as_text()
+    stack = cache["units"]["b0"]["k"].shape
+    whole = "bf16[%s]" % ",".join(map(str, stack))
+    copies = [line.strip()[:160] for line in hlo.splitlines()
+              if (m := re.search(r"= (.*?) (copy|copy-start)\(", line))
+              and whole + "{" in m.group(1)]
+    assert whole in hlo, "the stacked cache is not in the program"
+    assert not copies, copies
