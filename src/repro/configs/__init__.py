@@ -1,4 +1,5 @@
-"""Config registry: the 10 assigned architectures + the paper's model.
+"""Config registry: the 10 assigned architectures, the paper's model and
+DeepSeek-V2-Lite (latent attention).
 
 ``get_config(arch_id)`` returns the full published config;
 ``smoke_config(arch_id)`` returns a reduced same-family config for CPU
@@ -10,16 +11,17 @@ import dataclasses
 
 from repro.models.config import ModelConfig
 
-from . import (deepseek_moe_16b, granite_moe_3b_a800m, jamba_1_5_large_398b,
-               llama_moe_3p5b, llava_next_mistral_7b, minicpm_2b,
-               mistral_large_123b, musicgen_medium, qwen2_5_3b, smollm_135m,
-               xlstm_350m)
+from . import (deepseek_moe_16b, deepseek_v2_lite, granite_moe_3b_a800m,
+               jamba_1_5_large_398b, llama_moe_3p5b, llava_next_mistral_7b,
+               minicpm_2b, mistral_large_123b, musicgen_medium, qwen2_5_3b,
+               smollm_135m, xlstm_350m)
 from .shapes import SHAPES, ShapeSpec, shape_applies
 
 _MODULES = [
     granite_moe_3b_a800m, deepseek_moe_16b, jamba_1_5_large_398b,
     llava_next_mistral_7b, qwen2_5_3b, minicpm_2b, smollm_135m,
     mistral_large_123b, musicgen_medium, xlstm_350m, llama_moe_3p5b,
+    deepseek_v2_lite,
 ]
 
 REGISTRY: dict[str, ModelConfig] = {m.ARCH_ID: m.CONFIG for m in _MODULES}
@@ -39,7 +41,8 @@ def get_config(arch_id: str) -> ModelConfig:
 def smoke_config(arch_id: str) -> ModelConfig:
     """Reduced same-family config: small width/depth, few experts, tiny
     vocab — structure (pattern, GQA ratio, shared experts, frontend,
-    first-dense-layer) preserved."""
+    first-dense-layer, latent attention and its rotary scaling)
+    preserved."""
     cfg = get_config(arch_id)
     n_kv = max(1, round(4 * cfg.n_kv_heads / cfg.n_heads))
     while 4 % n_kv:
@@ -65,6 +68,10 @@ def smoke_config(arch_id: str) -> ModelConfig:
         attn_q_chunk=16,
         attn_kv_chunk=16,
         compute_dtype="float32",
+        kv_lora_rank=32 if cfg.kv_lora_rank else 0,
+        qk_nope_head_dim=16 if cfg.qk_nope_head_dim else 0,
+        qk_rope_head_dim=8 if cfg.qk_rope_head_dim else 0,
+        v_head_dim=16 if cfg.v_head_dim else 0,
     )
 
 

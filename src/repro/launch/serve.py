@@ -80,8 +80,15 @@ def plan_and_apply_placement(cfg, params, counts: np.ndarray,
 
     The router columns and the expert stacks of every MoE block are
     permuted in place (their buffers are donated): ``params`` must not
-    be used afterwards.
+    be used afterwards.  A model that holds only a share of the experts
+    is refused: a permutation of all of them cannot be applied to the
+    stacks of the share.
     """
+    if cfg.held_share:
+        raise ValueError(
+            f"{cfg.name}: holds {cfg.experts_held} of {cfg.n_experts} routed "
+            "experts; the placement permutes all of them and cannot be "
+            "applied to a held share")
     e = cfg.n_experts
     ring = TorusSpec(shape=(min(ep_ring, e),), wrap=True)
     plans, costs = [], {"theorem1": 0.0, "identity": 0.0}

@@ -76,8 +76,12 @@ def flash_attention(
     v: jnp.ndarray,
     q_positions: jnp.ndarray,   # (B, S) global positions of queries
     kv_positions: jnp.ndarray,  # (B, S)
+    scale: float | None = None,  # None => hd ** -0.5
 ) -> jnp.ndarray:
     """Causal online-softmax attention, chunked along both S axes.
+
+    ``v`` may be narrower than ``q``/``k`` (latent attention: qk width
+    192, v width 128); the output has v's width.
 
     With ``cfg.flash_vjp`` the backward pass recomputes probabilities
     chunk-wise (custom VJP) instead of letting scan-AD save every (qc,kc)
@@ -86,9 +90,9 @@ def flash_attention(
     every *train* cell (EXPERIMENTS.md §Perf iteration A1).
     """
     if cfg.flash_vjp:
-        out, _ = _flash_vjp_fn(cfg)(q, k, v, q_positions, kv_positions)
+        out, _ = _flash_vjp_fn(cfg, scale)(q, k, v, q_positions, kv_positions)
         return out
-    out, _ = _flash_fwd(cfg, q, k, v, q_positions, kv_positions)
+    out, _ = _flash_fwd(cfg, q, k, v, q_positions, kv_positions, scale)
     return out
 
 
@@ -99,15 +103,16 @@ def _flash_fwd(
     v: jnp.ndarray,
     q_positions: jnp.ndarray,
     kv_positions: jnp.ndarray,
+    scale: float | None = None,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """Returns (out (B,S,Hq,hd), lse (B,Hkv,G,S) log-sum-exp per query)."""
+    """Returns (out (B,S,Hq,dv), lse (B,Hkv,G,S) log-sum-exp per query)."""
     b, s, hq, hd = q.shape
-    hkv = k.shape[2]
+    hkv, dv = k.shape[2], v.shape[-1]
     g = hq // hkv
     qc = _pick_chunk(s, cfg.attn_q_chunk)
     kc = _pick_chunk(s, cfg.attn_kv_chunk)
     nq, nk = s // qc, s // kc
-    scale = hd ** -0.5
+    scale = hd ** -0.5 if scale is None else scale
 
     # (B, Hkv, G, S, hd) view of q; K/V stay (B, Hkv, S, hd).
     qg = q.reshape(b, s, hkv, g, hd).transpose(0, 2, 3, 1, 4)
@@ -117,7 +122,7 @@ def _flash_fwd(
     q_chunks = qg.reshape(b, hkv, g, nq, qc, hd).transpose(3, 0, 1, 2, 4, 5)
     qpos_chunks = q_positions.reshape(b, nq, qc).transpose(1, 0, 2)
     k_chunks = kt.reshape(b, hkv, nk, kc, hd).transpose(2, 0, 1, 3, 4)
-    v_chunks = vt.reshape(b, hkv, nk, kc, hd).transpose(2, 0, 1, 3, 4)
+    v_chunks = vt.reshape(b, hkv, nk, kc, dv).transpose(2, 0, 1, 3, 4)
     kpos_chunks = kv_positions.reshape(b, nk, kc).transpose(1, 0, 2)
 
     def q_step(_, q_in):
@@ -149,7 +154,7 @@ def _flash_fwd(
 
         m0 = jnp.full((b, hkv, g, qc), NEG_INF, dtype=jnp.float32)
         l0 = jnp.zeros((b, hkv, g, qc), dtype=jnp.float32)
-        a0 = jnp.zeros((b, hkv, g, qc, hd), dtype=jnp.float32)
+        a0 = jnp.zeros((b, hkv, g, qc, dv), dtype=jnp.float32)
         (m, l, acc), _ = jax.lax.scan(
             kv_step, (m0, l0, a0), (k_chunks, v_chunks, kpos_chunks)
         )
@@ -160,9 +165,9 @@ def _flash_fwd(
     _, (out_chunks, lse_chunks) = jax.lax.scan(
         q_step, None, (q_chunks, qpos_chunks)
     )
-    # (nq, B, Hkv, G, qc, hd) -> (B, S, Hq, hd)
-    out = out_chunks.transpose(1, 2, 3, 0, 4, 5).reshape(b, hkv, g, s, hd)
-    out = out.transpose(0, 3, 1, 2, 4).reshape(b, s, hq, hd)
+    # (nq, B, Hkv, G, qc, dv) -> (B, S, Hq, dv)
+    out = out_chunks.transpose(1, 2, 3, 0, 4, 5).reshape(b, hkv, g, s, dv)
+    out = out.transpose(0, 3, 1, 2, 4).reshape(b, s, hq, dv)
     lse = lse_chunks.transpose(1, 2, 3, 0, 4).reshape(b, hkv, g, s)
     return out, lse
 
@@ -176,7 +181,7 @@ def _mask_block(cfg: ModelConfig, qpos, kpos):
 
 
 @functools.lru_cache(maxsize=None)
-def _flash_vjp_fn(cfg: ModelConfig):
+def _flash_vjp_fn(cfg: ModelConfig, scale: float | None = None):
     """custom-VJP flash attention: O(qc*kc) backward temporaries."""
     if cfg.attn_logit_softcap > 0:
         raise NotImplementedError(
@@ -185,31 +190,32 @@ def _flash_vjp_fn(cfg: ModelConfig):
 
     @jax.custom_vjp
     def flash(q, k, v, qpos, kpos):
-        return _flash_fwd(cfg, q, k, v, qpos, kpos)
+        return _flash_fwd(cfg, q, k, v, qpos, kpos, scale)
 
     def fwd(q, k, v, qpos, kpos):
-        out, lse = _flash_fwd(cfg, q, k, v, qpos, kpos)
+        out, lse = _flash_fwd(cfg, q, k, v, qpos, kpos, scale)
         return (out, lse), (q, k, v, qpos, kpos, out, lse)
 
     def bwd(res, cts):
         do, _ = cts                      # no cotangent flows into lse
         q, k, v, qpos, kpos, out, lse = res
         b, s, hq, hd = q.shape
-        hkv = k.shape[2]
+        hkv, dv = k.shape[2], v.shape[-1]
         g = hq // hkv
         qc = _pick_chunk(s, cfg.attn_q_chunk)
         kc = _pick_chunk(s, cfg.attn_kv_chunk)
         nq, nk = s // qc, s // kc
-        scale = hd ** -0.5
+        sc = hd ** -0.5 if scale is None else scale
         f32 = jnp.float32
         # chunk intermediates ride in the model dtype (bf16 on TPU: halves
         # the backward's HBM traffic; accumulation stays f32 via
         # preferred_element_type) — f32 inputs keep f32 for exact tests.
         wdt = q.dtype
 
-        def grouped(x):                  # (B,S,Hq,hd) -> (nq,B,Hkv,G,qc,hd)
-            xg = x.reshape(b, s, hkv, g, hd).transpose(0, 2, 3, 1, 4)
-            return xg.reshape(b, hkv, g, nq, qc, hd).transpose(3, 0, 1, 2, 4, 5)
+        def grouped(x):                  # (B,S,Hq,w) -> (nq,B,Hkv,G,qc,w)
+            w = x.shape[-1]
+            xg = x.reshape(b, s, hkv, g, w).transpose(0, 2, 3, 1, 4)
+            return xg.reshape(b, hkv, g, nq, qc, w).transpose(3, 0, 1, 2, 4, 5)
 
         q_chunks = grouped(q)
         do_chunks = grouped(do.astype(wdt))
@@ -223,7 +229,7 @@ def _flash_vjp_fn(cfg: ModelConfig):
         kt = k.transpose(0, 2, 1, 3)                   # (B,Hkv,S,hd)
         vt = v.transpose(0, 2, 1, 3)
         k_chunks = kt.reshape(b, hkv, nk, kc, hd).transpose(2, 0, 1, 3, 4)
-        v_chunks = vt.reshape(b, hkv, nk, kc, hd).transpose(2, 0, 1, 3, 4)
+        v_chunks = vt.reshape(b, hkv, nk, kc, dv).transpose(2, 0, 1, 3, 4)
         kpos_chunks = kpos.reshape(b, nk, kc).transpose(1, 0, 2)
 
         def kv_step(dq_acc, kv_in):
@@ -233,7 +239,7 @@ def _flash_vjp_fn(cfg: ModelConfig):
                 dk_blk, dv_blk = carry
                 q_blk, do_blk, lse_blk, dl_blk, qpb = q_in
                 sco = jnp.einsum("bngqd,bnkd->bngqk", q_blk, k_blk,
-                                 preferred_element_type=f32) * scale
+                                 preferred_element_type=f32) * sc
                 sco = _softcap(sco, cfg.attn_logit_softcap)
                 mask = _mask_block(cfg, qpb, kpb)[:, None, None]
                 p = jnp.where(mask, jnp.exp(sco - lse_blk[..., None]), 0.0)
@@ -242,16 +248,16 @@ def _flash_vjp_fn(cfg: ModelConfig):
                                              preferred_element_type=f32)
                 dp = jnp.einsum("bngqd,bnkd->bngqk", do_blk, v_blk,
                                 preferred_element_type=f32)
-                ds = (p * (dp - dl_blk[..., None]) * scale).astype(wdt)
+                ds = (p * (dp - dl_blk[..., None]) * sc).astype(wdt)
                 dq_blk = jnp.einsum("bngqk,bnkd->bngqd", ds, k_blk,
                                     preferred_element_type=f32)
                 dk_blk = dk_blk + jnp.einsum("bngqk,bngqd->bnkd", ds, q_blk,
                                              preferred_element_type=f32)
                 return (dk_blk, dv_blk), dq_blk
 
-            zeros_kv = jnp.zeros((b, hkv, kc, hd), f32)
             (dk_blk, dv_blk), dq_parts = jax.lax.scan(
-                q_step, (zeros_kv, zeros_kv),
+                q_step, (jnp.zeros((b, hkv, kc, hd), f32),
+                         jnp.zeros((b, hkv, kc, dv), f32)),
                 (q_chunks, do_chunks, lse_chunks, delta_chunks, qpos_chunks),
             )
             return dq_acc + dq_parts, (dk_blk, dv_blk)
@@ -264,9 +270,9 @@ def _flash_vjp_fn(cfg: ModelConfig):
         dq = dq.transpose(0, 3, 1, 2, 4).reshape(b, s, hq, hd).astype(q.dtype)
         dk = dk_chunks.transpose(1, 2, 0, 3, 4).reshape(b, hkv, s, hd)
         dk = dk.transpose(0, 2, 1, 3).astype(k.dtype)
-        dv = dv_chunks.transpose(1, 2, 0, 3, 4).reshape(b, hkv, s, hd)
-        dv = dv.transpose(0, 2, 1, 3).astype(v.dtype)
-        return dq, dk, dv, None, None
+        dvs = dv_chunks.transpose(1, 2, 0, 3, 4).reshape(b, hkv, s, dv)
+        dvs = dvs.transpose(0, 2, 1, 3).astype(v.dtype)
+        return dq, dk, dvs, None, None
 
     flash.defvjp(fwd, bwd)
     return flash
@@ -347,7 +353,8 @@ def attention_decode(
 
 def _write_rows(cache: jnp.ndarray, rows: jnp.ndarray, pos: jnp.ndarray,
                 unit) -> jnp.ndarray:
-    """Write row b of ``rows`` (B, 1, Hkv, hd) at ``[unit,] b, pos[b]``.
+    """Write row b of ``rows`` (B, 1, *row) at ``[unit,] b, pos[b]``: a row
+    is (Hkv, hd) of K or V, or one latent-cache row.
 
     One dynamic-update-slice per sequence: it is done in place whatever
     layout the compiler gives the cache (a scatter asks for ``hd`` minor,
@@ -357,9 +364,10 @@ def _write_rows(cache: jnp.ndarray, rows: jnp.ndarray, pos: jnp.ndarray,
     rows = rows.astype(cache.dtype)
     lead = () if unit is None else (unit,)
     for i in range(rows.shape[0]):
-        row = rows[i].reshape((1,) * (cache.ndim - 2) + rows.shape[2:])
-        cache = jax.lax.dynamic_update_slice(cache, row,
-                                             (*lead, i, pos[i], 0, 0))
+        row = rows[i].reshape((1,) * (cache.ndim - rows.ndim + 2)
+                              + rows.shape[2:])
+        cache = jax.lax.dynamic_update_slice(
+            cache, row, (*lead, i, pos[i]) + (0,) * (rows.ndim - 2))
     return cache
 
 

@@ -16,8 +16,21 @@ import dataclasses
 class LayerSpec:
     """One block of the repeating pattern."""
 
-    mixer: str = "attn"     # "attn" | "mamba" | "mlstm" | "slstm"
+    mixer: str = "attn"     # "attn" | "mla" | "mamba" | "mlstm" | "slstm"
     ffn: str = "dense"      # "dense" | "moe" | "none"
+
+
+@dataclasses.dataclass(frozen=True)
+class YarnScaling:
+    """YaRN rotary scaling (arXiv:2309.00071) as DeepSeek-V2 publishes it
+    under ``rope_scaling`` with ``type`` "yarn"."""
+
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 def _round_up(x: int, m: int) -> int:
@@ -45,12 +58,21 @@ class ModelConfig:
     first_dense_d_ff: int = 0
     capacity_factor: float = 1.25
     router_jitter: float = 0.0
+    n_experts_held: int = 0              # this chip's share of the routed
+    #   experts (the first n of an expert-parallel split); 0 => all
 
     # --- attention ---
     qkv_bias: bool = False               # qwen2.5
     rope_theta: float = 10000.0
     attn_logit_softcap: float = 0.0
     sliding_window: int = 0              # 0 => full causal
+    rope_scaling: YarnScaling | None = None
+
+    # --- latent attention (mixer "mla", DeepSeek-V2) ---
+    kv_lora_rank: int = 0                # latent width cached per token
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0            # rotary part, shared by the heads
+    v_head_dim: int = 0
 
     # --- SSM / recurrent ---
     mamba_d_state: int = 16
@@ -97,6 +119,19 @@ class ModelConfig:
                 raise ValueError(f"{self.name}: MoE pattern needs n_experts/top_k")
             if self.d_ff_expert == 0:
                 object.__setattr__(self, "d_ff_expert", self.d_ff)
+            if not 0 <= self.n_experts_held <= self.n_experts:
+                raise ValueError(f"{self.name}: n_experts_held="
+                                 f"{self.n_experts_held} of {self.n_experts}")
+            if self.held_share and self.moe_slotting:
+                raise ValueError(f"{self.name}: a held share of the experts "
+                                 "has no EP slot layout")
+        if any(s.mixer == "mla" for s in self.pattern) and not (
+                self.kv_lora_rank and self.qk_nope_head_dim
+                and self.qk_rope_head_dim and self.v_head_dim
+                and self.rope_scaling is not None):
+            raise ValueError(f"{self.name}: the mla mixer needs kv_lora_rank, "
+                             "the nope, rope and v head sizes and YaRN "
+                             "rope_scaling")
 
     # ------------------------------------------------------------------ #
     @property
@@ -114,6 +149,21 @@ class ModelConfig:
     @property
     def kv_dim(self) -> int:
         return self.n_kv_heads * self.head_dim
+
+    @property
+    def experts_held(self) -> int:
+        """Routed experts whose weights this model holds."""
+        return self.n_experts_held or self.n_experts
+
+    @property
+    def held_share(self) -> bool:
+        """True where only a share of the routed experts is held."""
+        return self.experts_held < self.n_experts
+
+    @property
+    def latent_dim(self) -> int:
+        """Width of one latent-cache row: the latent and the rotary key."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
 
     @property
     def d_inner(self) -> int:
@@ -136,7 +186,11 @@ class ModelConfig:
 
     # -- parameter counting (for roofline MODEL_FLOPS = 6*N*D) ----------- #
     def param_counts(self) -> dict[str, float]:
-        """Approximate parameter counts: total and active-per-token."""
+        """Approximate parameter counts: total and active-per-token.
+
+        With a held share of the routed experts, the counts are of what is
+        held: its experts, and a token's expected top-k hits among them.
+        """
         d = self.d_model
         emb = self.padded_vocab * d * (1 if self.tie_embeddings else 2)
         total = float(emb)
@@ -150,6 +204,12 @@ class ModelConfig:
                 dff = self.d_ff
             if spec.mixer == "attn":
                 p = d * (self.q_dim + 2 * self.kv_dim) + self.q_dim * d
+            elif spec.mixer == "mla":
+                h, r = self.n_heads, self.kv_lora_rank
+                p = d * h * (self.qk_nope_head_dim + self.qk_rope_head_dim) \
+                    + d * self.latent_dim + r \
+                    + r * h * (self.qk_nope_head_dim + self.v_head_dim) \
+                    + h * self.v_head_dim * d
             elif spec.mixer == "mamba":
                 di = self.d_inner
                 p = d * 2 * di + di * self.mamba_d_conv \
@@ -165,8 +225,9 @@ class ModelConfig:
                 active += 3 * d * dff
             elif spec.ffn == "moe":
                 e = 3 * d * self.d_ff_expert
-                total += self.n_experts * e + self.n_shared_experts * e \
+                held = self.experts_held
+                total += held * e + self.n_shared_experts * e \
                     + d * self.n_experts
-                active += self.top_k * e + self.n_shared_experts * e \
-                    + d * self.n_experts
+                active += self.top_k * held / self.n_experts * e \
+                    + self.n_shared_experts * e + d * self.n_experts
         return {"total": total, "active": active}

@@ -1,6 +1,8 @@
 """Shared primitives: norms, initializers, rotary embeddings, FFN."""
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -14,6 +16,11 @@ STAGES = (
     "moe.router", "moe.dispatch", "moe.experts", "moe.combine", "moe.shared",
     "ffn.dense", "head", "layers",
 )
+#: Scopes nested inside the attention stages of the latent-attention mixer
+#: (``repro.models.mla``): K/V expanded from the latent (prefill), queries
+#: taken into and outputs out of latent space (decode), and the scores and
+#: weighted sum over the latent cache (decode).
+MLA_SCOPES = ("mla.expand", "mla.absorb", "mla.latent")
 
 
 def dtype_of(name: str):
@@ -72,6 +79,56 @@ def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float) -> jnp.ndar
     cos = jnp.cos(angles)[..., None, :]                           # (..., S, 1, half)
     sin = jnp.sin(angles)[..., None, :]
     x1, x2 = x[..., :half], x[..., half:]
+    rotated = jnp.concatenate(
+        [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1
+    )
+    return rotated.astype(x.dtype)
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention temperature factor, 0.1 m ln(s) + 1 (1 for s <= 1)."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_frequencies(dim: int, theta: float, scaling) -> jnp.ndarray:
+    """YaRN inverse frequencies, shape (dim // 2,), float32: wavelengths
+    below the correction range keep their frequency, those above it are
+    divided by ``factor``, with a linear ramp between (DeepSeek-V2's
+    ``DeepseekV2YarnRotaryEmbedding``; arXiv:2309.00071)."""
+    def correction_dim(rotations):
+        return (dim * math.log(scaling.original_max_position_embeddings
+                               / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(correction_dim(scaling.beta_fast)), 0)
+    high = min(math.ceil(correction_dim(scaling.beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    extra = rope_frequencies(dim, theta)
+    inter = extra / scaling.factor
+    ramp = jnp.clip((jnp.arange(dim // 2, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    keep = 1.0 - ramp
+    return inter * (1.0 - keep) + extra * keep
+
+
+def apply_rope_pairs(x: jnp.ndarray, positions: jnp.ndarray, theta: float,
+                     scaling) -> jnp.ndarray:
+    """YaRN rotary embedding in DeepSeek-V2's layout: rotated pairs are
+    adjacent (dims 2i, 2i+1), and the result comes out de-interleaved (the
+    even dims rotated, then the odd), as the published
+    ``apply_rotary_pos_emb`` does; the frequencies and the cos/sin factor
+    are YaRN's.  x: (..., seq, n_heads, dim); positions: (..., seq)."""
+    dim = x.shape[-1]
+    freqs = yarn_frequencies(dim, theta, scaling)
+    m = (yarn_mscale(scaling.factor, scaling.mscale)
+         / yarn_mscale(scaling.factor, scaling.mscale_all_dim))
+    angles = positions[..., :, None].astype(jnp.float32) * freqs
+    cos = jnp.cos(angles)[..., None, :]
+    sin = jnp.sin(angles)[..., None, :]
+    if m != 1.0:
+        cos, sin = cos * m, sin * m
+    x1, x2 = x[..., 0::2], x[..., 1::2]
     rotated = jnp.concatenate(
         [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1
     )

@@ -2,7 +2,8 @@
 
 The layer stack is a ``lax.scan`` over repeating pattern units (HLO size
 independent of depth).  Each unit applies its pattern of
-(mixer, ffn) blocks; mixers are attention / mamba / mlstm / slstm, FFNs
+(mixer, ffn) blocks; mixers are attention / latent attention (mla) /
+mamba / mlstm / slstm, FFNs
 are dense SwiGLU or MoE.  Decode carries a per-unit cache pytree (KV cache
 for attention, recurrent state for SSM blocks) stacked along the unit axis
 through the scan; each block writes only its own rows of it.
@@ -22,6 +23,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from . import attention as attn
+from . import mla
 from . import moe as moe_mod
 from . import ssm
 from .config import LayerSpec, ModelConfig
@@ -70,6 +72,8 @@ def _block_init(key, cfg: ModelConfig, spec: LayerSpec, dtype) -> dict:
     p: dict[str, Any] = {"norm1": rmsnorm_init(cfg.d_model, jnp.float32)}
     if spec.mixer == "attn":
         p["mixer"] = attn.attn_init(km, cfg, dtype)
+    elif spec.mixer == "mla":
+        p["mixer"] = mla.mla_init(km, cfg, dtype)
     elif spec.mixer == "mamba":
         p["mixer"] = ssm.mamba_init(km, cfg, dtype)
     elif spec.mixer == "mlstm":
@@ -135,7 +139,8 @@ def _apply_mixer(cfg, spec, bp, x, positions, par, cdt, cache, mode,
     With ``unit`` (decode inside the layer scan) ``cache`` is the stack
     over units and ``unit`` this block's index in it: attention writes its
     new K/V rows into the stack, a recurrent block its whole state at
-    ``unit``.  The stack comes back updated in place.
+    ``unit``.  The stack comes back updated in place.  Latent attention
+    (``mla``) writes its latent rows the way attention writes K/V.
     """
     if spec.mixer == "attn":
         if mode == "train":
@@ -144,6 +149,12 @@ def _apply_mixer(cfg, spec, bp, x, positions, par, cdt, cache, mode,
             return attn.attention_prefill(cfg, bp["mixer"], x, positions, cache, cdt)
         return attn.attention_decode(cfg, bp["mixer"], x, positions, cache, cdt,
                                      unit)
+    if spec.mixer == "mla":
+        if mode == "train":
+            return mla.mla_forward(cfg, bp["mixer"], x, positions, cdt), None
+        if mode == "prefill":
+            return mla.mla_prefill(cfg, bp["mixer"], x, positions, cache, cdt)
+        return mla.mla_decode(cfg, bp["mixer"], x, positions, cache, cdt, unit)
     if spec.mixer in ("mamba", "mlstm"):
         fwd, dec = {"mamba": (ssm.mamba_forward, ssm.mamba_decode),
                     "mlstm": (ssm.mlstm_forward, ssm.mlstm_decode)}[spec.mixer]
@@ -324,6 +335,8 @@ def loss_fn(cfg: ModelConfig, params: dict, batch: dict,
 def _block_cache(cfg, spec: LayerSpec, batch: int, max_len: int, cdt):
     if spec.mixer == "attn":
         return attn.init_kv_cache(cfg, batch, max_len, cdt)
+    if spec.mixer == "mla":
+        return mla.init_latent_cache(cfg, batch, max_len, cdt)
     if spec.mixer == "mamba":
         return ssm.mamba_init_state(cfg, batch)
     if spec.mixer == "mlstm":

@@ -107,10 +107,12 @@ def virtual_indices(idx: jnp.ndarray, sl: Slotting) -> jnp.ndarray:
 
 
 def moe_init(key, cfg: ModelConfig, dtype) -> dict:
+    """A MoE layer's weights: the router over all ``n_experts``, and the
+    stacks of the experts held (``cfg.experts_held``) and shared."""
     kr, kg, ku, kd, ks = jax.random.split(key, 5)
-    e, d, f = cfg.n_experts, cfg.d_model, cfg.d_ff_expert
+    e, d, f = cfg.experts_held, cfg.d_model, cfg.d_ff_expert
     p = {
-        "router": normal_init(kr, (d, e), jnp.float32),  # router kept fp32
+        "router": normal_init(kr, (d, cfg.n_experts), jnp.float32),  # fp32
         "w_gate": normal_init(kg, (e, d, f), dtype),
         "w_up": normal_init(ku, (e, d, f), dtype),
         "w_down": out_proj_init(kd, (e, f, d), dtype, cfg.n_layers),
@@ -254,19 +256,53 @@ def _combine(flat_out: jnp.ndarray, plan, weights: jnp.ndarray, t: int,
     return jnp.einsum("tkd,tk->td", per_copy, weights.astype(compute_dtype))
 
 
+def _group_part(cfg: ModelConfig, params: dict, xt: jnp.ndarray,
+                weights: jnp.ndarray, v_idx: jnp.ndarray, group, cap: int,
+                frag: int, compute_dtype) -> jnp.ndarray:
+    """The part of the routed sum that one group of experts gives: the
+    ``loc`` stacked in ``params`` are buckets ``[group * loc, (group + 1)
+    * loc)``.  Every token is routed; copies bound for other groups go to
+    a trash bucket (local id ``loc``) whose output is zero.  ``group`` is
+    the device's index on the expert-parallel axis, or 0 for the share of
+    experts one chip holds."""
+    t, d = xt.shape
+    loc = params["w_gate"].shape[0]
+    is_mine = (v_idx // loc) == group
+    local_idx = jnp.where(is_mine, v_idx - group * loc, loc)
+    buckets, plan = _dispatch(xt, local_idx, loc + 1, cap, cfg.top_k * frag,
+                              compute_dtype)
+    buckets = buckets.reshape(loc + 1, cap, d)
+    outs = expert_ffn(params, buckets[:loc], compute_dtype)
+    outs = jnp.concatenate(
+        [outs, jnp.zeros((1, cap, d), outs.dtype)], axis=0
+    )                                                   # zero trash bucket
+    return _combine(outs.reshape((loc + 1) * cap, d), plan, weights, t,
+                    cfg.top_k, frag, compute_dtype)
+
+
 def moe_apply_local(cfg: ModelConfig, params: dict, x: jnp.ndarray,
                     compute_dtype) -> tuple[jnp.ndarray, dict]:
-    """Single-shard MoE: x (B, S, d) -> (B, S, d).  Test oracle + CPU path."""
+    """Single-shard MoE: x (B, S, d) -> (B, S, d).  Test oracle + CPU path.
+
+    With a held share (``cfg.n_experts_held`` < ``n_experts``) the layer is
+    one device's body of expert parallelism without the exchange: it routes
+    over all experts and adds only its held experts' part of the routed
+    sum (group 0, the first ``n_experts_held``), plus the shared experts.
+    """
     b, s, d = x.shape
     t = b * s
     xt = x.reshape(t, d).astype(compute_dtype)
     weights, idx, aux = route(cfg, params["router"], xt)
     v_idx, n_b, cap, frag = _plan(cfg, idx, t)
-    buckets, plan = _dispatch(xt, v_idx, n_b, cap, cfg.top_k * frag,
-                              compute_dtype)
-    outs = expert_ffn(params, buckets.reshape(n_b, cap, d), compute_dtype)
-    y = _combine(outs.reshape(n_b * cap, d), plan, weights, t, cfg.top_k,
-                 frag, compute_dtype)
+    if cfg.held_share:
+        y = _group_part(cfg, params, xt, weights, v_idx, 0, cap, frag,
+                        compute_dtype)
+    else:
+        buckets, plan = _dispatch(xt, v_idx, n_b, cap, cfg.top_k * frag,
+                                  compute_dtype)
+        outs = expert_ffn(params, buckets.reshape(n_b, cap, d), compute_dtype)
+        y = _combine(outs.reshape(n_b * cap, d), plan, weights, t, cfg.top_k,
+                     frag, compute_dtype)
     if cfg.n_shared_experts > 0:
         y = y + _shared_ffn(params["shared"], xt, compute_dtype)
     return y.reshape(b, s, d), aux
@@ -348,19 +384,8 @@ def moe_apply_ep_replicated(cfg: ModelConfig, params: dict,
     if n_b != loc * n_dev:
         raise ValueError(f"bucket count {n_b} != {loc}x{n_dev} local stacks")
 
-    # Map global bucket ids to local ids; foreign copies go to a trash
-    # bucket (local id loc) whose output is forced to zero.
-    is_mine = (v_idx // loc) == my
-    local_idx = jnp.where(is_mine, v_idx - my * loc, loc)
-    buckets, plan = _dispatch(xt, local_idx, loc + 1, cap, cfg.top_k * frag,
-                              compute_dtype)
-    buckets = buckets.reshape(loc + 1, cap, d)
-    outs = expert_ffn(params, buckets[:loc], compute_dtype)
-    outs = jnp.concatenate(
-        [outs, jnp.zeros((1, cap, d), outs.dtype)], axis=0
-    )                                                   # zero trash bucket
-    y = _combine(outs.reshape((loc + 1) * cap, d), plan, weights, t,
-                 cfg.top_k, frag, compute_dtype)
+    y = _group_part(cfg, params, xt, weights, v_idx, my, cap, frag,
+                    compute_dtype)
     y = jax.lax.psum(y, axis_name)
     if cfg.n_shared_experts > 0:
         y = y + _shared_ffn(params["shared"], xt, compute_dtype)  # replicated

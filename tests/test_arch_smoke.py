@@ -15,7 +15,8 @@ ALL_ARCHS = list_archs()
 
 def test_registry_complete():
     assert len(ASSIGNED) == 10
-    assert len(ALL_ARCHS) == 11          # + the paper's llama-moe-3.5b
+    # + the paper's llama-moe-3.5b and deepseek-v2-lite (latent attention)
+    assert len(ALL_ARCHS) == 12
 
 
 @pytest.mark.parametrize("arch", ALL_ARCHS)
@@ -42,6 +43,7 @@ def test_param_counts_match_public_sizes():
         "mistral-large-123b": (123e9, 0.10),
         "xlstm-350m": (350e6, 0.35),
         "llama-moe-3.5b": (6.7e9, 0.15),
+        "deepseek-v2-lite": (15.7e9, 0.05),
     }
     for arch, (target, tol) in expect.items():
         total = get_config(arch).param_counts()["total"]
@@ -55,6 +57,9 @@ def test_active_params():
     assert 2.0e9 < a < 4.5e9
     a = get_config("llama-moe-3.5b").param_counts()["active"]
     assert 3.0e9 < a < 4.2e9
+    # deepseek-v2-lite: 2.4B activated, with embedding and head 2.66B
+    a = get_config("deepseek-v2-lite").param_counts()["active"]
+    assert 2.4e9 < a < 2.8e9
 
 
 @pytest.mark.slow

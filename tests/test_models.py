@@ -9,6 +9,7 @@ from repro.models import (LayerSpec, ModelConfig, apply_placement,
                           prefill, random_batch)
 from repro.models.attention import flash_attention
 from repro.models.config import ModelConfig as MC
+from repro.models.config import YarnScaling
 from repro.models.moe import (capacity, dispatch_indices, moe_apply_local,
                               moe_init, route)
 
@@ -173,6 +174,9 @@ ARCH_CASES = {
     "slstm": dict(pattern=(LayerSpec("slstm", "none"),), tie_embeddings=True),
     "hybrid": dict(pattern=(LayerSpec("attn", "dense"),
                             LayerSpec("mamba", "dense")), n_layers=4),
+    "mla": dict(pattern=(LayerSpec("mla", "moe"),), n_experts=4, top_k=2,
+                d_ff_expert=16, capacity_factor=2.0, kv_lora_rank=16,
+                qk_nope_head_dim=8, qk_rope_head_dim=4, v_head_dim=8),
 }
 
 
@@ -200,6 +204,13 @@ TEACHER_CASES = {
     "hybrid": ARCH_CASES["hybrid"],     # + a whole mamba state at the unit
     "first_layer_dense": dict(ARCH_CASES["moe"], n_layers=3,
                               first_layer_dense=True),  # its own buffer
+    # one latent row a layer, absorbed decode against the expanded forward;
+    # YaRN over a short original length, and a held share of the experts
+    "mla_held": dict(ARCH_CASES["mla"], n_layers=3, first_layer_dense=True,
+                     n_experts=8, n_experts_held=4, capacity_factor=4.0,
+                     rope_scaling=YarnScaling(
+                         factor=4.0, original_max_position_embeddings=8,
+                         mscale=0.707, mscale_all_dim=0.5)),
 }
 
 

@@ -28,6 +28,7 @@ from repro.kernels.moe_gmm import gmm
 from repro.launch.steps import make_serve_step
 from repro.models import (LayerSpec, ModelConfig, Parallel, init_cache,
                           init_params)
+from repro.models.config import YarnScaling
 
 
 @pytest.fixture(scope="module")
@@ -97,8 +98,10 @@ def test_decode_attention_compiles_llama_moe(one_chip):
     _assert_mosaic(decode_attention, q, kv, kv, pos)
 
 
-# Decode widths of the benchmark's two serving cells, at a few layers: the
-# cache plumbing of the layer scan does not depend on depth.
+# Decode widths of the benchmark's serving cells, at a few layers: the
+# cache plumbing of the layer scan does not depend on depth.  The latent
+# attention cell's step is compiled whole: 128 sequences of 2,048
+# positions, 21 scan units.
 SERVE_WIDTHS = {
     "granite-moe-3b-a800m": dict(
         cfg=dict(d_model=1536, n_heads=24, n_kv_heads=8, head_dim=64,
@@ -111,17 +114,31 @@ SERVE_WIDTHS = {
                  d_ff_expert=1408, capacity_factor=64 / 6, vocab_size=102400,
                  first_layer_dense=True, first_dense_d_ff=10944),
         batch=8, max_len=640),
+    "deepseek-v2-lite": dict(
+        cfg=dict(d_model=2048, n_heads=16, n_kv_heads=16, n_experts=64,
+                 n_experts_held=8, top_k=6, n_shared_experts=2, d_ff=1408,
+                 d_ff_expert=1408, capacity_factor=64 / 6, vocab_size=102400,
+                 first_layer_dense=True, first_dense_d_ff=10944,
+                 pattern=(LayerSpec("mla", "moe"),), kv_lora_rank=512,
+                 qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+                 rope_scaling=YarnScaling(
+                     factor=40.0, original_max_position_embeddings=4096,
+                     mscale=0.707, mscale_all_dim=0.707),
+                 n_layers=22, norm_eps=1e-6),
+        batch=128, max_len=2048, rows_minor=("latent",)),
 }
 
 
 @pytest.mark.parametrize("model", list(SERVE_WIDTHS))
 def test_serve_step_updates_stacked_cache_in_place(one_chip, model):
     """The decode step with its cache donated: no op copies the whole
-    stacked K or V cache, so each step writes only the new rows."""
+    stacked K or V cache (or latent cache), so each step writes only the
+    new rows."""
     w = SERVE_WIDTHS[model]
-    cfg = ModelConfig(name=model, n_layers=3, pattern=(LayerSpec("attn", "moe"),),
-                      param_dtype="bfloat16", compute_dtype="bfloat16",
-                      **w["cfg"])
+    cfg = ModelConfig(**{"name": model, "n_layers": 3,
+                         "pattern": (LayerSpec("attn", "moe"),),
+                         "param_dtype": "bfloat16",
+                         "compute_dtype": "bfloat16", **w["cfg"]})
     b, max_len = w["batch"], w["max_len"]
     on_chip = functools.partial(jax.tree.map, lambda s: _struct(
         s.shape, s.dtype, one_chip))
@@ -133,10 +150,14 @@ def test_serve_step_updates_stacked_cache_in_place(one_chip, model):
     pos = _struct((b,), jnp.int32, one_chip)
     hlo = jax.jit(make_serve_step(cfg, Parallel()), donate_argnums=(1,)) \
         .lower(params, cache, tok, pos, None).compile().as_text()
-    stack = cache["units"]["b0"]["k"].shape
-    whole = "bf16[%s]" % ",".join(map(str, stack))
-    copies = [line.strip()[:160] for line in hlo.splitlines()
-              if (m := re.search(r"= (.*?) (copy|copy-start)\(", line))
-              and whole + "{" in m.group(1)]
-    assert whole in hlo, "the stacked cache is not in the program"
-    assert not copies, copies
+    for name, stack in cache["units"]["b0"].items():
+        whole = "bf16[%s]" % ",".join(map(str, stack.shape))
+        copies = [line.strip()[:160] for line in hlo.splitlines()
+                  if (m := re.search(r"= (.*?) (copy|copy-start)\(", line))
+                  and whole + "{" in m.group(1)]
+        assert whole in hlo, "the stacked cache is not in the program"
+        assert not copies, copies
+        if name in w.get("rows_minor", ()):
+            # The latent rows, padded to the lanes, stay minor: a row
+            # written is contiguous, not a column across the positions.
+            assert whole + "{3,2,1,0:" in hlo
