@@ -14,6 +14,7 @@ import jax.numpy as jnp
 from .decode_attn import decode_attention as _decode_attention
 from .deposit import deposit as _deposit
 from .deposit import deposit_segments as _deposit_segments
+from .mla_decode import mla_decode_attention as _mla_decode_attention
 from .moe_gmm import gmm as _gmm
 
 
@@ -92,3 +93,17 @@ def decode_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         interpret = not on_tpu()
     return _decode_attention(q, k, v, pos, block_s=block_s,
                              interpret=interpret)
+
+
+def mla_decode_attention(q: jnp.ndarray, stack: jnp.ndarray,
+                         unit: jnp.ndarray, pos: jnp.ndarray, *,
+                         scale: float, block_b: int | None = None,
+                         block_s: int | None = None,
+                         interpret: bool | None = None) -> jnp.ndarray:
+    """Latent-attention flash-decode over layer ``unit`` of a stacked
+    latent cache: (B,H,W) f32 out."""
+    if interpret is None:
+        interpret = not on_tpu()
+    return _mla_decode_attention(q, stack, unit, pos, scale=scale,
+                                 block_b=block_b, block_s=block_s,
+                                 interpret=interpret)

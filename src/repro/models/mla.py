@@ -15,7 +15,9 @@ Two paths compute the same attention:
 - decode, absorbed: the query's nope part goes into latent space through
   W_uk (the K half of ``w_kv_b``) and is scored against the cached latents
   directly; the weighted sum of latents comes out through W_uv (the V
-  half) and ``w_o``.  K and V are never expanded.
+  half) and ``w_o``.  K and V are never expanded.  On a TPU the scores
+  and the weighted sum are one Pallas kernel (``kernels/mla_decode.py``)
+  that reads each sequence's latent rows once, up to its position.
 
 The cache holds one row per token and layer, shared by every head: the
 normed latent and the rotated key, r + rope wide (576 for DeepSeek-V2),
@@ -37,6 +39,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from ..kernels import ops
 from .attention import NEG_INF, _write_rows, flash_attention
 from .config import ModelConfig
 from .layers import (apply_rope_pairs, normal_init, out_proj_init,
@@ -172,9 +175,7 @@ def mla_decode(cfg: ModelConfig, params: dict, x: jnp.ndarray,
                                       q_rope[:, 0]], axis=-1), cfg)
     with jax.named_scope("attn.kv_write"):
         stack = _write_rows(cache["latent"], rows, pos, unit)
-    layer = stack if unit is None else jax.lax.dynamic_index_in_dim(
-        stack, unit, keepdims=False)
-    o_lat = _latent_core(cfg, q, layer, pos, compute_dtype)
+    o_lat = _attend_latent(cfg, q, stack, pos, unit, compute_dtype)
     with jax.named_scope("attn.out"):
         with jax.named_scope("mla.absorb"):
             o = jnp.einsum("bhr,rhv->bhv", o_lat[..., :r].astype(compute_dtype),
@@ -185,17 +186,36 @@ def mla_decode(cfg: ModelConfig, params: dict, x: jnp.ndarray,
     return y, {"latent": stack}
 
 
-@jax.named_scope("attn.core")
-def _latent_core(cfg: ModelConfig, q, latent, pos, compute_dtype):
+def _attend_latent(cfg: ModelConfig, q, stack, pos, unit, compute_dtype):
+    """The latent query over this layer's cached rows 0..pos: on a TPU the
+    one-pass Pallas kernel (``kernels/mla_decode.py``) over the whole
+    stack, elsewhere ``_latent_core`` over the layer's slice.  (B, H, W)
+    f32; only its first r columns are used."""
+    if unit is None:               # one layer's cache: a stack of one
+        stack, unit = stack[None], jnp.int32(0)
+    scale = softmax_scale(cfg)
+
+    def kernel(q, stack, unit, pos):
+        return ops.mla_decode_attention(q, stack, unit, pos, scale=scale,
+                                        interpret=False)
+
+    def core(q, stack, unit, pos):
+        layer = jax.lax.dynamic_index_in_dim(stack, unit, keepdims=False)
+        return _latent_core(q, layer, pos, scale, compute_dtype)
+
+    with jax.named_scope("attn.core"), jax.named_scope("mla.latent"):
+        return jax.lax.platform_dependent(q, stack, unit, pos, tpu=kernel,
+                                          default=core)
+
+
+def _latent_core(q, latent, pos, scale: float, compute_dtype):
     """One latent query per head over the sequence's cached rows:
     q (B, H, W), latent (B, S, W) -> the softmax-weighted sum of rows
-    (B, H, W), f32; only its first r columns are used."""
-    with jax.named_scope("mla.latent"):
-        lat = latent.astype(compute_dtype)
-        sco = jnp.einsum("bhc,bsc->bhs", q, lat,
-                         preferred_element_type=jnp.float32) \
-            * softmax_scale(cfg)
-        mask = jnp.arange(lat.shape[1])[None, :] <= pos[:, None]
-        p = jax.nn.softmax(jnp.where(mask[:, None, :], sco, NEG_INF), axis=-1)
-        return jnp.einsum("bhs,bsc->bhc", p.astype(compute_dtype), lat,
-                          preferred_element_type=jnp.float32)
+    (B, H, W), f32.  The kernel's reference."""
+    lat = latent.astype(compute_dtype)
+    sco = jnp.einsum("bhc,bsc->bhs", q, lat,
+                     preferred_element_type=jnp.float32) * scale
+    mask = jnp.arange(lat.shape[1])[None, :] <= pos[:, None]
+    p = jax.nn.softmax(jnp.where(mask[:, None, :], sco, NEG_INF), axis=-1)
+    return jnp.einsum("bhs,bsc->bhc", p.astype(compute_dtype), lat,
+                      preferred_element_type=jnp.float32)

@@ -4,8 +4,11 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels.ops import decode_attention, expert_ffn_pallas, gmm
+from repro.kernels.mla_decode import blocks
+from repro.kernels.ops import (decode_attention, expert_ffn_pallas, gmm,
+                               mla_decode_attention)
 from repro.kernels.ref import decode_attention_ref, gmm_ref
+from repro.models.mla import _latent_core
 
 
 def tol(dtype):
@@ -129,3 +132,52 @@ def test_decode_attn_matches_model_attention():
     ref = decode_attention_ref(q, k, v, pos)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=2e-5, rtol=2e-5)
+
+
+# --------------------------------------------------------------------- #
+# mla_decode
+# --------------------------------------------------------------------- #
+
+
+@pytest.mark.parametrize("dtype,units,unit,pos,block_b,block_s", [
+    # Positions at 0, at a block's edges (bs - 1, bs) and at S - 1, two
+    # sequences a block, so that each block stops at another row block;
+    # the other units of the stack hold other rows.
+    (jnp.float32, 3, 1, (0, 15, 16, 63), 2, 16),
+    (jnp.float32, 4, 3, (63, 16, 15, 0), 2, 16),
+    (jnp.bfloat16, 3, 2, (0, 15, 16, 63), 2, 16),
+    # One sequence a block, blocks from the shapes.
+    (jnp.float32, 3, 0, (40, 7, 63, 16), 1, None),
+    (jnp.float32, 3, 1, (5, 33, 48, 20), None, None),
+    # The dense first layer's cache: a stack of one.
+    (jnp.float32, 1, 0, (0, 15, 16, 63), 2, 16),
+    (jnp.bfloat16, 1, 0, (31, 32, 47, 62), 4, 16),
+])
+def test_mla_decode_attn_matches_latent_core(dtype, units, unit, pos,
+                                             block_b, block_s):
+    """The kernel over layer ``unit`` of a stack equals the model's jnp
+    latent core over that layer's slice: f32 to rounding, bf16 within
+    2e-2 (its unnormalised weights are rounded to bf16 before the value
+    product, the core's normalised ones)."""
+    b, h, s, w, scale = 4, 4, 64, 128, 0.2
+    ks = jax.random.split(jax.random.PRNGKey(11), 2)
+    q = jax.random.normal(ks[0], (b, h, w), dtype)
+    stack = jax.random.normal(ks[1], (units, b, s, w), dtype)
+    pos = jnp.asarray(pos, jnp.int32)
+    out = mla_decode_attention(q, stack, jnp.int32(unit), pos, scale=scale,
+                               block_b=block_b, block_s=block_s,
+                               interpret=True)
+    ref = _latent_core(q, stack[unit], pos, scale, dtype)
+    assert out.shape == (b, h, w) and out.dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               **tol(dtype))
+
+
+@pytest.mark.parametrize("b,s,w,want", [
+    (128, 2048, 640, (8, 512)),      # deepseek-v2-lite's decode step
+    (4, 64, 128, (4, 64)),
+    (6, 333, 128, (6, 333)),         # no 16-row divisor: whole rows
+    (3, 4096, 4096, (1, 512)),       # wide rows: one sequence a block
+])
+def test_mla_decode_blocks_from_shapes(b, s, w, want):
+    assert blocks(b, s, w, 2) == want

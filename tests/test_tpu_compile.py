@@ -24,6 +24,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.kernels.decode_attn import decode_attention
 from repro.kernels.deposit import deposit
+from repro.kernels.mla_decode import mla_decode_attention
 from repro.kernels.moe_gmm import gmm
 from repro.launch.steps import make_serve_step
 from repro.models import (LayerSpec, ModelConfig, Parallel, init_cache,
@@ -98,6 +99,17 @@ def test_decode_attention_compiles_llama_moe(one_chip):
     _assert_mosaic(decode_attention, q, kv, kv, pos)
 
 
+def test_mla_decode_attention_compiles_deepseek_v2_lite(one_chip):
+    """DeepSeek-V2-Lite decode: 128 sequences, 16 heads, latent rows of
+    576 padded to 640, a 2,048-position stack of 21 layers."""
+    q = _struct((128, 16, 640), jnp.bfloat16, one_chip)
+    stack = _struct((21, 128, 2048, 640), jnp.bfloat16, one_chip)
+    unit = _struct((), jnp.int32, one_chip)
+    pos = _struct((128,), jnp.int32, one_chip)
+    _assert_mosaic(functools.partial(mla_decode_attention, scale=0.1),
+                   q, stack, unit, pos)
+
+
 # Decode widths of the benchmark's serving cells, at a few layers: the
 # cache plumbing of the layer scan does not depend on depth.  The latent
 # attention cell's step is compiled whole: 128 sequences of 2,048
@@ -152,12 +164,22 @@ def test_serve_step_updates_stacked_cache_in_place(one_chip, model):
         .lower(params, cache, tok, pos, None).compile().as_text()
     for name, stack in cache["units"]["b0"].items():
         whole = "bf16[%s]" % ",".join(map(str, stack.shape))
+        shapes = [whole]
+        if name in w.get("rows_minor", ()):
+            # The latent core reads its layer out of the stack: no op
+            # copies one layer's rows either.
+            shapes.append("bf16[%s]" % ",".join(map(str, stack.shape[1:])))
         copies = [line.strip()[:160] for line in hlo.splitlines()
                   if (m := re.search(r"= (.*?) (copy|copy-start)\(", line))
-                  and whole + "{" in m.group(1)]
+                  and any(sh + "{" in m.group(1) for sh in shapes)]
         assert whole in hlo, "the stacked cache is not in the program"
         assert not copies, copies
         if name in w.get("rows_minor", ()):
             # The latent rows, padded to the lanes, stay minor: a row
             # written is contiguous, not a column across the positions.
             assert whole + "{3,2,1,0:" in hlo
+            # The latent core is the Pallas kernel, inside the stages
+            # that the trace readers look for.
+            kernels = [line for line in hlo.splitlines()
+                       if "custom_call_target=\"tpu_custom_call\"" in line]
+            assert any("/attn.core/mla.latent/" in line for line in kernels)
