@@ -86,7 +86,7 @@ def _harness_config(arch: str):
     cfg = smoke_config(arch)
     return dataclasses.replace(
         cfg, d_model=1024, d_ff_expert=2048, n_experts=4,
-        top_k=min(cfg.top_k, 4), n_shared_experts=0, moe_slotting=False)
+        top_k=min(cfg.top_k, 4), n_shared_experts=0)
 
 
 def _flat_topology(n_sats: int) -> TopologySample:

@@ -88,7 +88,10 @@ def deposit_segments(rows: jnp.ndarray, cols: jnp.ndarray,
 def decode_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                      pos: jnp.ndarray, *, block_s: int = 512,
                      interpret: bool | None = None) -> jnp.ndarray:
-    """GQA flash-decode over a KV cache: (B,Hkv,G,hd) out."""
+    """GQA flash-decode over a (B,Hkv,S,hd) KV cache: (B,Hkv,G,hd) out.
+
+    Calibration times it (``core.calibration.measure_components``); the
+    served decode step runs ``attention._decode_core`` instead."""
     if interpret is None:
         interpret = not on_tpu()
     return _decode_attention(q, k, v, pos, block_s=block_s,

@@ -87,7 +87,7 @@ def flash_attention(
     chunk-wise (custom VJP) instead of letting scan-AD save every (qc,kc)
     probability block — which otherwise materializes the full S^2 attention
     matrix per layer during backprop and dominates the memory roofline of
-    every *train* cell (EXPERIMENTS.md §Perf iteration A1).
+    every *train* cell.
     """
     if cfg.flash_vjp:
         out, _ = _flash_vjp_fn(cfg, scale)(q, k, v, q_positions, kv_positions)
@@ -327,8 +327,9 @@ def attention_decode(
     stack over the layer scan's units (U, B, S, Hkv, hd) and this layer's
     index in it.  Writes the B new k/v rows at ``[unit,] b, pos[b]`` and
     attends over this layer's cache[0..pos]; the cache comes back with only
-    those rows changed.  This is the jnp reference path; the Pallas
-    ``decode_attn`` kernel implements the same contract for TPU.
+    those rows changed.  The attention core is jnp on every platform;
+    the Pallas ``decode_attn`` kernel takes a (B, Hkv, S, hd) cache and is
+    not on this path (calibration times it).
     """
     b = x.shape[0]
     positions = pos[:, None]                                   # (B, 1)
@@ -375,18 +376,6 @@ def _write_rows(cache: jnp.ndarray, rows: jnp.ndarray, pos: jnp.ndarray,
 def _decode_core(cfg: ModelConfig, qg, ck, cv, pos, compute_dtype):
     """One query per sequence over its cache: (B, Hkv, G, hd) -> same."""
     s_max, hd = ck.shape[1], cfg.head_dim
-    if cfg.use_pallas_decode and cfg.sliding_window == 0 \
-            and cfg.attn_logit_softcap == 0:
-        # Pallas flash-decode kernel: one HBM pass over the cache.  (The
-        # cache transpose to (B,Hkv,S,hd) is layout-only; a production
-        # deployment keeps the cache in kernel layout.)
-        from repro.kernels.ops import decode_attention as _pallas_decode
-        return _pallas_decode(
-            qg.astype(compute_dtype),
-            ck.transpose(0, 2, 1, 3).astype(compute_dtype),
-            cv.transpose(0, 2, 1, 3).astype(compute_dtype),
-            pos,
-        )
     kt = ck.astype(compute_dtype)
     vt = cv.astype(compute_dtype)
     sco = jnp.einsum("bngd,bsnd->bngs", qg, kt,

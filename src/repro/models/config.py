@@ -57,7 +57,6 @@ class ModelConfig:
     first_layer_dense: bool = False      # deepseek-moe: layer 0 is dense FFN
     first_dense_d_ff: int = 0
     capacity_factor: float = 1.25
-    router_jitter: float = 0.0
     n_experts_held: int = 0              # this chip's share of the routed
     #   experts (the first n of an expert-parallel split); 0 => all
 
@@ -96,13 +95,8 @@ class ModelConfig:
     attn_q_chunk: int = 512
     attn_kv_chunk: int = 1024
     remat: str = "unit"                  # "none" | "unit"
-    moe_impl: str = "einsum"             # "einsum" (GShard-style) | "ragged"
-    moe_slotting: bool = False           # EP slot layout (pad/fragment) so
-    moe_ep_slots: int = 16               #   any E runs expert-parallel
     flash_vjp: bool = False              # custom-VJP flash attention (bwd
     #   recomputes P chunk-wise instead of saving it; see attention.py)
-    use_pallas_decode: bool = False      # decode attention via the Pallas
-    #   flash-decode kernel (kernels/decode_attn); interpret-mode on CPU
 
     def __post_init__(self):
         if self.n_layers % len(self.pattern) != 0:
@@ -122,9 +116,6 @@ class ModelConfig:
             if not 0 <= self.n_experts_held <= self.n_experts:
                 raise ValueError(f"{self.name}: n_experts_held="
                                  f"{self.n_experts_held} of {self.n_experts}")
-            if self.held_share and self.moe_slotting:
-                raise ValueError(f"{self.name}: a held share of the experts "
-                                 "has no EP slot layout")
         if any(s.mixer == "mla" for s in self.pattern) and not (
                 self.kv_lora_rank and self.qk_nope_head_dim
                 and self.qk_rope_head_dim and self.v_head_dim

@@ -38,7 +38,6 @@ class Parallel:
     mesh: Mesh | None = None
     data_axes: tuple[str, ...] = ("data",)   # batch axes ("pod","data") multi-pod
     model_axis: str = "model"
-    moe_mode: str = "auto"    # "auto" | "ep" | "ep_rep" | "local"
 
     @property
     def model_size(self) -> int:
@@ -49,13 +48,7 @@ class Parallel:
     def resolve_moe(self, cfg: ModelConfig, seq_len: int) -> str:
         if self.mesh is None or self.model_size == 1:
             return "local"
-        if self.moe_mode != "auto":
-            return self.moe_mode
-        n_buckets = cfg.n_experts
-        sl = moe_mod.slotting_for(cfg)
-        if sl is not None:
-            n_buckets = sl.n_virtual
-        if n_buckets % self.model_size == 0:
+        if cfg.n_experts % self.model_size == 0:
             if seq_len % self.model_size == 0:
                 return "ep"        # sequence-sharded all-to-all dispatch
             return "ep_rep"        # replicated-token EP (decode)

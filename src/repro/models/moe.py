@@ -8,14 +8,22 @@ einsum — that is what makes 64-expert configs viable.
 
 Execution paths
 ---------------
-- ``moe_apply_local``: single-shard math (also the oracle for tests).
+``Parallel.resolve_moe`` (``repro.models.model``) picks one from the mesh
+and the shapes alone:
+
+- ``moe_apply_local``: single-shard math, the served path and the oracle
+  for tests.  With a held share (``cfg.n_experts_held``) it is one
+  device's part of an expert-parallel layer, without the exchange.
 - ``moe_apply_ep``: expert parallelism inside ``shard_map`` — tokens are
   sequence-sharded over the EP axis, buckets travel via ``lax.all_to_all``,
   each device runs its local expert group, and a reverse all-to-all brings
-  results home.  Requires E % |EP axis| == 0.
+  results home.  Needs E % |EP axis| == 0 and S % |EP axis| == 0.
+- ``moe_apply_ep_replicated``: the same expert split for tokens
+  replicated over the EP axis (decode, where S does not divide over it):
+  each device adds its experts' part and one psum combines.
 - TP fallback for E not divisible by the axis (e.g. granite's 40 experts on
-  16 devices): experts' d_ff is sharded over the axis instead and partial
-  outputs are psum-reduced; selected automatically by the model layer.
+  16 devices): ``moe_apply_local`` with the experts' d_ff sharded over the
+  axis by the weight sharding; partial outputs are psum-reduced.
 
 SpaceMoE placement enters as a *checkpoint transform*: ``apply_placement``
 permutes the stacked expert weights and the router's output columns so
@@ -33,79 +41,6 @@ from .config import ModelConfig
 from .layers import normal_init, out_proj_init
 
 
-# --------------------------------------------------------------------- #
-# EP slotting (perf feature; paper Sec. VI-B multi-expert rule on devices)
-#
-# The EP all-to-all path needs the expert-stack's leading dim to divide the
-# EP axis.  Slotting makes that true for ANY expert count by re-laying the
-# stack into "virtual slots":
-#   E >= S:  pad with dummy experts to the next multiple of S
-#            (granite: 40 -> 48, 3 slots/device; dummies get no tokens);
-#   E <  S:  fragment each expert's d_ff into S/E' slices after padding E
-#            to a divisor of S (llama-moe: 8 experts x 2 half-experts = 16
-#            slots; fragment outputs sum to the exact expert output).
-# Without slotting these configs fall back to TP over d_ff, whose
-# all-reduces made granite/llama-moe train cells collective-bound by ~100x
-# (see EXPERIMENTS.md §Perf).
-# --------------------------------------------------------------------- #
-import dataclasses
-
-
-@dataclasses.dataclass(frozen=True)
-class Slotting:
-    n_experts: int
-    n_slots: int       # EP axis size the layout targets
-    frag: int          # d_ff fragments per expert
-    e_pad: int         # padded expert count (>= n_experts)
-
-    @property
-    def n_virtual(self) -> int:
-        return self.e_pad * self.frag
-
-
-def make_slotting(n_experts: int, n_slots: int) -> Slotting:
-    if n_experts >= n_slots:
-        e_pad = -(-n_experts // n_slots) * n_slots
-        return Slotting(n_experts, n_slots, 1, e_pad)
-    e_pad = n_experts
-    while n_slots % e_pad:
-        e_pad += 1
-    return Slotting(n_experts, n_slots, n_slots // e_pad, e_pad)
-
-
-def slotting_for(cfg: ModelConfig) -> Slotting | None:
-    if not getattr(cfg, "moe_slotting", False) or cfg.n_experts == 0:
-        return None
-    return make_slotting(cfg.n_experts, cfg.moe_ep_slots)
-
-
-def slotted_weights(w_gate, w_up, w_down, sl: Slotting):
-    """Canonical (E,d,f)/(E,f,d) stacks -> virtual (V,d,f/frag)/(V,f/frag,d)."""
-    e, d, f = w_gate.shape
-    if f % sl.frag:
-        raise ValueError(f"d_ff_expert={f} not divisible by frag={sl.frag}")
-    pad = sl.e_pad - e
-    if pad:
-        w_gate = jnp.concatenate([w_gate, jnp.zeros((pad, d, f), w_gate.dtype)])
-        w_up = jnp.concatenate([w_up, jnp.zeros((pad, d, f), w_up.dtype)])
-        w_down = jnp.concatenate([w_down, jnp.zeros((pad, f, d), w_down.dtype)])
-    fs = f // sl.frag
-    # (E', d, f) -> (E', frag, d, fs) -> (V, d, fs), slot-major per expert
-    wg = w_gate.reshape(sl.e_pad, d, sl.frag, fs).transpose(0, 2, 1, 3) \
-        .reshape(sl.n_virtual, d, fs)
-    wu = w_up.reshape(sl.e_pad, d, sl.frag, fs).transpose(0, 2, 1, 3) \
-        .reshape(sl.n_virtual, d, fs)
-    wd = w_down.reshape(sl.e_pad, sl.frag, fs, d).reshape(sl.n_virtual, fs, d)
-    return wg, wu, wd
-
-
-def virtual_indices(idx: jnp.ndarray, sl: Slotting) -> jnp.ndarray:
-    """(T, K) expert ids -> (T, K*frag) virtual slot ids."""
-    frag_ids = jnp.arange(sl.frag, dtype=idx.dtype)
-    v = idx[..., None] * sl.frag + frag_ids          # (T, K, frag)
-    return v.reshape(idx.shape[0], -1)
-
-
 def moe_init(key, cfg: ModelConfig, dtype) -> dict:
     """A MoE layer's weights: the router over all ``n_experts``, and the
     stacks of the experts held (``cfg.experts_held``) and shared."""
@@ -117,11 +52,6 @@ def moe_init(key, cfg: ModelConfig, dtype) -> dict:
         "w_up": normal_init(ku, (e, d, f), dtype),
         "w_down": out_proj_init(kd, (e, f, d), dtype, cfg.n_layers),
     }
-    sl = slotting_for(cfg)
-    if sl is not None:
-        p["w_gate"], p["w_up"], p["w_down"] = slotted_weights(
-            p["w_gate"], p["w_up"], p["w_down"], sl
-        )
     if cfg.n_shared_experts > 0:
         fs = f * cfg.n_shared_experts
         k1, k2, k3 = jax.random.split(ks, 3)
@@ -221,18 +151,9 @@ def _shared_ffn(params: dict, x: jnp.ndarray, compute_dtype) -> jnp.ndarray:
     return (g * u) @ params["w_down"].astype(compute_dtype)
 
 
-def _plan(cfg: ModelConfig, idx: jnp.ndarray, t: int):
-    """Virtual-slot dispatch plan: (v_idx, n_buckets, cap, frag)."""
-    sl = slotting_for(cfg)
-    if sl is None:
-        return idx, cfg.n_experts, capacity(cfg, t, cfg.n_experts), 1
-    return (virtual_indices(idx, sl), sl.n_virtual,
-            capacity(cfg, t, sl.e_pad), sl.frag)
-
-
 @jax.named_scope("moe.dispatch")
 def _dispatch(xt: jnp.ndarray, idx: jnp.ndarray, n_buckets: int, cap: int,
-              copies_per_token: int, compute_dtype):
+              compute_dtype):
     """Gather each token copy into its capacity-padded bucket.
 
     Returns the (n_buckets * cap, d) buckets and the plan ``(copy_slot,
@@ -240,44 +161,44 @@ def _dispatch(xt: jnp.ndarray, idx: jnp.ndarray, n_buckets: int, cap: int,
     slot_token, slot_valid, copy_slot, copy_kept = dispatch_indices(
         idx, n_buckets, cap
     )
-    copies = jnp.repeat(xt, copies_per_token, axis=0)
+    copies = jnp.repeat(xt, idx.shape[1], axis=0)
     buckets = copies[slot_token] * slot_valid[:, None].astype(compute_dtype)
     return buckets, (copy_slot, copy_kept)
 
 
 @jax.named_scope("moe.combine")
-def _combine(flat_out: jnp.ndarray, plan, weights: jnp.ndarray, t: int,
-             k: int, frag: int, compute_dtype) -> jnp.ndarray:
+def _combine(flat_out: jnp.ndarray, plan, weights: jnp.ndarray,
+             compute_dtype) -> jnp.ndarray:
     """(n_buckets * cap, d) bucket outputs -> (T, d): gather each copy's
-    output, sum fragments, weight top-K."""
+    output, weight top-K."""
     copy_slot, copy_kept = plan
+    t, k = weights.shape
     gathered = flat_out[copy_slot] * copy_kept[:, None].astype(compute_dtype)
-    per_copy = gathered.reshape(t, k, frag, -1).sum(axis=2)
+    per_copy = gathered.reshape(t, k, -1)
     return jnp.einsum("tkd,tk->td", per_copy, weights.astype(compute_dtype))
 
 
-def _group_part(cfg: ModelConfig, params: dict, xt: jnp.ndarray,
-                weights: jnp.ndarray, v_idx: jnp.ndarray, group, cap: int,
-                frag: int, compute_dtype) -> jnp.ndarray:
+def _group_part(params: dict, xt: jnp.ndarray, weights: jnp.ndarray,
+                idx: jnp.ndarray, group, cap: int,
+                compute_dtype) -> jnp.ndarray:
     """The part of the routed sum that one group of experts gives: the
-    ``loc`` stacked in ``params`` are buckets ``[group * loc, (group + 1)
+    ``loc`` stacked in ``params`` are experts ``[group * loc, (group + 1)
     * loc)``.  Every token is routed; copies bound for other groups go to
     a trash bucket (local id ``loc``) whose output is zero.  ``group`` is
     the device's index on the expert-parallel axis, or 0 for the share of
     experts one chip holds."""
-    t, d = xt.shape
+    d = xt.shape[1]
     loc = params["w_gate"].shape[0]
-    is_mine = (v_idx // loc) == group
-    local_idx = jnp.where(is_mine, v_idx - group * loc, loc)
-    buckets, plan = _dispatch(xt, local_idx, loc + 1, cap, cfg.top_k * frag,
-                              compute_dtype)
+    is_mine = (idx // loc) == group
+    local_idx = jnp.where(is_mine, idx - group * loc, loc)
+    buckets, plan = _dispatch(xt, local_idx, loc + 1, cap, compute_dtype)
     buckets = buckets.reshape(loc + 1, cap, d)
     outs = expert_ffn(params, buckets[:loc], compute_dtype)
     outs = jnp.concatenate(
         [outs, jnp.zeros((1, cap, d), outs.dtype)], axis=0
     )                                                   # zero trash bucket
-    return _combine(outs.reshape((loc + 1) * cap, d), plan, weights, t,
-                    cfg.top_k, frag, compute_dtype)
+    return _combine(outs.reshape((loc + 1) * cap, d), plan, weights,
+                    compute_dtype)
 
 
 def moe_apply_local(cfg: ModelConfig, params: dict, x: jnp.ndarray,
@@ -291,18 +212,16 @@ def moe_apply_local(cfg: ModelConfig, params: dict, x: jnp.ndarray,
     """
     b, s, d = x.shape
     t = b * s
+    e = cfg.n_experts
     xt = x.reshape(t, d).astype(compute_dtype)
     weights, idx, aux = route(cfg, params["router"], xt)
-    v_idx, n_b, cap, frag = _plan(cfg, idx, t)
+    cap = capacity(cfg, t, e)
     if cfg.held_share:
-        y = _group_part(cfg, params, xt, weights, v_idx, 0, cap, frag,
-                        compute_dtype)
+        y = _group_part(params, xt, weights, idx, 0, cap, compute_dtype)
     else:
-        buckets, plan = _dispatch(xt, v_idx, n_b, cap, cfg.top_k * frag,
-                                  compute_dtype)
-        outs = expert_ffn(params, buckets.reshape(n_b, cap, d), compute_dtype)
-        y = _combine(outs.reshape(n_b * cap, d), plan, weights, t, cfg.top_k,
-                     frag, compute_dtype)
+        buckets, plan = _dispatch(xt, idx, e, cap, compute_dtype)
+        outs = expert_ffn(params, buckets.reshape(e, cap, d), compute_dtype)
+        y = _combine(outs.reshape(e * cap, d), plan, weights, compute_dtype)
     if cfg.n_shared_experts > 0:
         y = y + _shared_ffn(params["shared"], xt, compute_dtype)
     return y.reshape(b, s, d), aux
@@ -335,15 +254,15 @@ def moe_apply_ep(cfg: ModelConfig, params: dict, x_local: jnp.ndarray,
     n_dev = axis_size(axis_name)
     b, s, d = x_local.shape
     t = b * s
-    loc = params["w_gate"].shape[0]          # local buckets (experts/slots)
+    e = cfg.n_experts
+    loc = params["w_gate"].shape[0]          # local experts
+    if e != loc * n_dev:
+        raise ValueError(f"bucket count {e} != {loc}x{n_dev} local stacks")
     xt = x_local.reshape(t, d).astype(compute_dtype)
     weights, idx, aux = route(cfg, params["router"], xt)
-    v_idx, n_b, cap, frag = _plan(cfg, idx, t)
-    if n_b != loc * n_dev:
-        raise ValueError(f"bucket count {n_b} != {loc}x{n_dev} local stacks")
+    cap = capacity(cfg, t, e)
 
-    buckets, plan = _dispatch(xt, v_idx, n_b, cap, cfg.top_k * frag,
-                              compute_dtype)
+    buckets, plan = _dispatch(xt, idx, e, cap, compute_dtype)
     buckets = buckets.reshape(n_dev, loc, cap, d)             # dest-device major
 
     # exchange buckets: after a2a, axis 0 indexes the *source* device.
@@ -355,8 +274,7 @@ def moe_apply_ep(cfg: ModelConfig, params: dict, x_local: jnp.ndarray,
     back = outs.reshape(loc, n_dev, cap, d).transpose(1, 0, 2, 3)
     home = jax.lax.all_to_all(back, axis_name, split_axis=0, concat_axis=0,
                               tiled=False)
-    y = _combine(home.reshape(n_b * cap, d), plan, weights, t, cfg.top_k,
-                 frag, compute_dtype)
+    y = _combine(home.reshape(e * cap, d), plan, weights, compute_dtype)
     if cfg.n_shared_experts > 0:
         y = y + _shared_ffn(params["shared"], xt, compute_dtype)
     return y.reshape(b, s, d), aux
@@ -377,14 +295,14 @@ def moe_apply_ep_replicated(cfg: ModelConfig, params: dict,
     my = jax.lax.axis_index(axis_name)
     b, s, d = x_local.shape
     t = b * s
+    e = cfg.n_experts
     loc = params["w_gate"].shape[0]
+    if e != loc * n_dev:
+        raise ValueError(f"bucket count {e} != {loc}x{n_dev} local stacks")
     xt = x_local.reshape(t, d).astype(compute_dtype)
     weights, idx, aux = route(cfg, params["router"], xt)
-    v_idx, n_b, cap, frag = _plan(cfg, idx, t)
-    if n_b != loc * n_dev:
-        raise ValueError(f"bucket count {n_b} != {loc}x{n_dev} local stacks")
 
-    y = _group_part(cfg, params, xt, weights, v_idx, my, cap, frag,
+    y = _group_part(params, xt, weights, idx, my, capacity(cfg, t, e),
                     compute_dtype)
     y = jax.lax.psum(y, axis_name)
     if cfg.n_shared_experts > 0:

@@ -1,72 +1,12 @@
-"""Perf-feature correctness: EP slotting and custom-VJP flash attention
-must be bit-compatible (within fp tolerance) with the baseline math."""
-import dataclasses
-
+"""Custom-VJP flash attention (``ModelConfig.flash_vjp``) must give the
+gradients of the plain attention math, within fp tolerance."""
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.models.attention import flash_attention
-from repro.models.config import LayerSpec, ModelConfig
-from repro.models.moe import (make_slotting, moe_apply_local, moe_init,
-                              slotted_weights, slotting_for)
-
-F32 = jnp.float32
-
-
-# --------------------------------------------------------------------- #
-# EP slotting
-# --------------------------------------------------------------------- #
-
-
-@pytest.mark.parametrize("e,s,frag,e_pad", [
-    (8, 16, 2, 8),        # llama-moe: fragment
-    (40, 16, 1, 48),      # granite: pad with dummies
-    (6, 16, 2, 8),        # pad then fragment
-    (64, 16, 1, 64),      # deepseek: already divisible
-    (16, 16, 1, 16),      # jamba: exact
-])
-def test_make_slotting(e, s, frag, e_pad):
-    sl = make_slotting(e, s)
-    assert (sl.frag, sl.e_pad) == (frag, e_pad)
-    assert sl.n_virtual % s == 0
-
-
-def _moe_cfg(e, k, slotting, dff=32):
-    return ModelConfig(
-        name="t", n_layers=2, d_model=32, n_heads=4, n_kv_heads=2, d_ff=64,
-        vocab_size=128, pattern=(LayerSpec("attn", "moe"),), n_experts=e,
-        top_k=k, d_ff_expert=dff, capacity_factor=8.0,
-        compute_dtype="float32", moe_slotting=slotting, moe_ep_slots=16,
-    )
-
-
-@pytest.mark.slow
-@pytest.mark.parametrize("e,k", [(8, 2), (40, 8), (6, 2), (64, 6)])
-def test_slotted_moe_matches_canonical(e, k):
-    cfg0, cfg1 = _moe_cfg(e, k, False), _moe_cfg(e, k, True)
-    p0 = moe_init(jax.random.PRNGKey(0), cfg0, F32)
-    sl = slotting_for(cfg1)
-    wg, wu, wd = slotted_weights(p0["w_gate"], p0["w_up"], p0["w_down"], sl)
-    p1 = dict(p0, w_gate=wg, w_up=wu, w_down=wd)
-    x = jax.random.normal(jax.random.PRNGKey(1), (2, 16, 32), F32)
-    y0, _ = moe_apply_local(cfg0, p0, x, F32)
-    y1, _ = moe_apply_local(cfg1, p1, x, F32)
-    np.testing.assert_allclose(np.asarray(y0), np.asarray(y1), atol=1e-5)
-
-
-def test_slotted_init_shapes():
-    cfg = _moe_cfg(8, 2, True)
-    p = moe_init(jax.random.PRNGKey(0), cfg, F32)
-    assert p["w_gate"].shape == (16, 32, 16)      # 8 experts x 2 half-slots
-    assert p["w_down"].shape == (16, 16, 32)
-    assert p["router"].shape == (32, 8)           # router stays expert-level
-
-
-# --------------------------------------------------------------------- #
-# custom-VJP flash attention
-# --------------------------------------------------------------------- #
+from repro.models.config import ModelConfig
 
 
 def _naive(q, k, v, pos, sliding=0):
@@ -129,28 +69,3 @@ def test_flash_vjp_whole_model_grads():
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    atol=2e-4, rtol=2e-3)
 
-
-# --------------------------------------------------------------------- #
-# Pallas decode kernel wired into the model decode path
-# --------------------------------------------------------------------- #
-
-
-def test_pallas_decode_path_matches_jnp():
-    from repro.models import decode_step, init_params, prefill, random_batch
-    base = dict(name="t", n_layers=2, d_model=32, n_heads=4, n_kv_heads=2,
-                d_ff=64, vocab_size=128, attn_q_chunk=8, attn_kv_chunk=8,
-                compute_dtype="float32")
-    cfg0 = ModelConfig(**base)
-    cfg1 = ModelConfig(**base, use_pallas_decode=True)
-    params = init_params(cfg0, jax.random.PRNGKey(0))
-    b, s = 2, 16
-    batch = random_batch(cfg0, b, s, seed=1)
-    prompt = {k: v for k, v in batch.items() if k != "labels"}
-    _, cache0 = prefill(cfg0, params, prompt, max_len=s + 4)
-    _, cache1 = prefill(cfg1, params, prompt, max_len=s + 4)
-    tok = jnp.full((b, 1), 3, jnp.int32)
-    pos = jnp.full((b,), s, jnp.int32)
-    l0, _ = decode_step(cfg0, params, cache0, tok, pos)
-    l1, _ = decode_step(cfg1, params, cache1, tok, pos)
-    np.testing.assert_allclose(np.asarray(l0), np.asarray(l1),
-                               atol=2e-4, rtol=2e-4)
