@@ -15,6 +15,7 @@ from .decode_attn import decode_attention as _decode_attention
 from .deposit import deposit as _deposit
 from .deposit import deposit_segments as _deposit_segments
 from .mla_decode import mla_decode_attention as _mla_decode_attention
+from .moe_decode import expert_ffn_hit as _expert_ffn_hit
 from .moe_gmm import gmm as _gmm
 
 
@@ -59,6 +60,19 @@ def expert_ffn_pallas(params: dict, xs: jnp.ndarray, compute_dtype,
     gate = jax.nn.silu(gmm(xs, wg, interpret=interpret))
     up = gmm(xs, wu, interpret=interpret)
     return gmm(gate * up, wd, interpret=interpret)
+
+
+def expert_ffn_hit(xs: jnp.ndarray, w_gate: jnp.ndarray, w_up: jnp.ndarray,
+                   w_down: jnp.ndarray, unit: jnp.ndarray, ids: jnp.ndarray,
+                   n_hit: jnp.ndarray, *,
+                   interpret: bool | None = None) -> jnp.ndarray:
+    """The decode expert FFN of the experts ``ids[:n_hit]`` with layer
+    ``unit`` of the (U,E,...) weight stacks: (E,C,d) out, zero for the
+    experts not hit."""
+    if interpret is None:
+        interpret = not on_tpu()
+    return _expert_ffn_hit(xs, w_gate, w_up, w_down, unit, ids, n_hit,
+                           interpret=interpret)
 
 
 def deposit(rows: jnp.ndarray, cols: jnp.ndarray, vals: jnp.ndarray,

@@ -170,10 +170,10 @@ def _apply_mixer(cfg, spec, bp, x, positions, par, cdt, cache, mode,
         cache, state)
 
 
-def _apply_moe(cfg, bp_ffn, x, par: Parallel, cdt):
+def _apply_moe(cfg, bp_ffn, x, par: Parallel, cdt, unit=None):
     mode = par.resolve_moe(cfg, x.shape[1])
     if mode == "local":
-        return moe_mod.moe_apply_local(cfg, bp_ffn, x, cdt)
+        return moe_mod.moe_apply_local(cfg, bp_ffn, x, cdt, unit)
     mesh = par.mesh
     n_data = 1
     for a in par.data_axes:
@@ -210,7 +210,10 @@ def _apply_moe(cfg, bp_ffn, x, par: Parallel, cdt):
 
 
 def _apply_block(cfg, spec, bp, x, positions, par, cdt, cache, mode,
-                 unit=None):
+                 unit=None, moe_unit=None):
+    """One (mixer, ffn) block.  ``moe_unit``: the MoE block's routed
+    expert weights are the stacks over every unit and this is its index
+    (decode; see ``decode_step``)."""
     aux = None
     h = rmsnorm(bp["norm1"], x, cfg.norm_eps)
     y, new_cache = _apply_mixer(cfg, spec, bp, h, positions, par, cdt, cache,
@@ -221,21 +224,27 @@ def _apply_block(cfg, spec, bp, x, positions, par, cdt, cache, mode,
         if spec.ffn == "dense":
             x = x + ffn_apply(bp["ffn"], h, cdt)
         else:
-            out, aux = _apply_moe(cfg, bp["ffn"], h, par, cdt)
+            out, aux = _apply_moe(cfg, bp["ffn"], h, par, cdt, moe_unit)
             x = x + out
     return x, new_cache, aux
 
 
 def _apply_unit(cfg, unit_params, x, positions, par, cdt, unit_cache, mode,
-                unit=None):
+                unit=None, experts=None):
+    """``experts``: block name -> the routed experts' weight stacks over
+    every unit, which that block's MoE reads at ``unit`` (decode)."""
     new_caches = {}
     aux_sum = jnp.zeros((), jnp.float32)
     counts = jnp.zeros((max(cfg.n_experts, 1),), jnp.float32)
     for i, spec in enumerate(cfg.pattern):
         cache_i = None if unit_cache is None else unit_cache.get(f"b{i}")
+        bp, moe_unit = unit_params[f"b{i}"], None
+        if experts and f"b{i}" in experts:
+            bp = {**bp, "ffn": {**bp["ffn"], **experts[f"b{i}"]}}
+            moe_unit = unit
         x, nc, aux = _apply_block(
-            cfg, spec, unit_params[f"b{i}"], x, positions, par, cdt, cache_i,
-            mode, unit
+            cfg, spec, bp, x, positions, par, cdt, cache_i, mode, unit,
+            moe_unit
         )
         if nc is not None:
             new_caches[f"b{i}"] = nc
@@ -354,6 +363,19 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int) -> dict:
     return out
 
 
+def _expert_stacks(cfg: ModelConfig, units: dict) -> tuple[dict, dict]:
+    """Split the routed experts' weight stacks of every MoE block out of
+    the stacked unit params: (the rest, block name -> its stacks)."""
+    rest, stacks = dict(units), {}
+    for i, spec in enumerate(cfg.pattern):
+        if spec.ffn == "moe":
+            b = f"b{i}"
+            ffn = dict(units[b]["ffn"])
+            stacks[b] = {k: ffn.pop(k) for k in ("w_gate", "w_up", "w_down")}
+            rest[b] = {**units[b], "ffn": ffn}
+    return rest, stacks
+
+
 def decode_step(cfg: ModelConfig, params: dict, cache: dict,
                 tokens: jnp.ndarray, pos: jnp.ndarray,
                 par: Parallel = Parallel(), embeds: jnp.ndarray | None = None):
@@ -368,6 +390,12 @@ def decode_step(cfg: ModelConfig, params: dict, cache: dict,
     of it; each recurrent block writes its whole state at the index.  The
     returned stack is the final carry, so a donated cache is updated in
     place rather than rebuilt layer by layer.
+
+    Where the step's tokens cannot hit every routed expert
+    (``moe.reads_hit_experts``, single shard), the MoE blocks' expert
+    weight stacks stay out of the scanned unit params: the scan closes
+    over them and each layer reads its hit experts from the whole stacks
+    at its unit index, as attention reads its cache.
     """
     cdt = dtype_of(cfg.compute_dtype)
     if embeds is not None:
@@ -382,17 +410,22 @@ def decode_step(cfg: ModelConfig, params: dict, cache: dict,
         )
         new_cache["first"] = fc
 
+    unit_params, experts = params["units"], None
+    if par.mesh is None and moe_mod.reads_hit_experts(
+            cfg, x.shape[0] * x.shape[1]):
+        unit_params, experts = _expert_stacks(cfg, unit_params)
+
     def unit_step(carry, xs):
         x, caches = carry
         unit_params, unit = xs
         x, caches, _, _ = _apply_unit(cfg, unit_params, x, pos, par, cdt,
-                                      caches, "decode", unit)
+                                      caches, "decode", unit, experts)
         return (x, caches), None
 
     units = jnp.arange(n_scan_units(cfg), dtype=jnp.int32)
     with jax.named_scope("layers"):
         (x, new_cache["units"]), _ = jax.lax.scan(
-            unit_step, (x, cache["units"]), (params["units"], units)
+            unit_step, (x, cache["units"]), (unit_params, units)
         )
     x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
     table = params["embed"] if cfg.tie_embeddings else params["head"]

@@ -14,6 +14,10 @@ and the shapes alone:
 - ``moe_apply_local``: single-shard math, the served path and the oracle
   for tests.  With a held share (``cfg.n_experts_held``) it is one
   device's part of an expert-parallel layer, without the exchange.
+  Inside the decode layer scan, where a step's T·K token copies cannot
+  hit every expert (``reads_hit_experts``), it computes only the experts
+  hit and reads only their weights, from the whole stacks (on a TPU the
+  Pallas kernel ``kernels/moe_decode.py``).
 - ``moe_apply_ep``: expert parallelism inside ``shard_map`` — tokens are
   sequence-sharded over the EP axis, buckets travel via ``lax.all_to_all``,
   each device runs its local expert group, and a reverse all-to-all brings
@@ -37,6 +41,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..compat import axis_size, shard_map
+from ..kernels import ops
 from .config import ModelConfig
 from .layers import normal_init, out_proj_init
 
@@ -144,6 +149,54 @@ def expert_ffn(params: dict, xs: jnp.ndarray, compute_dtype) -> jnp.ndarray:
     return jnp.einsum("ecf,efd->ecd", gate * up, wd)
 
 
+def reads_hit_experts(cfg: ModelConfig, n_tokens: int) -> bool:
+    """Whether a decode step of ``n_tokens`` tokens computes only the
+    routed experts its copies hit (``moe_apply_local`` with ``unit``): a
+    full layer, not a held share, whose T·K copies cannot hit every
+    expert, so that at least E - T·K experts' weights go unread."""
+    return not cfg.held_share and n_tokens * cfg.top_k < cfg.n_experts
+
+
+def hit_experts(idx: jnp.ndarray, n_experts: int):
+    """The experts that the token copies ``idx`` (T, K) are routed to:
+    their ids ascending in min(E, T·K) slots, the slots past the last hit
+    repeating it, and how many there are."""
+    iota = jnp.arange(n_experts, dtype=jnp.int32)
+    hit = jnp.zeros((n_experts,), bool).at[idx.reshape(-1)].set(True)
+    ids = jnp.sort(jnp.where(hit, iota, n_experts))[:min(n_experts, idx.size)]
+    return (jnp.minimum(ids, jnp.max(jnp.where(hit, iota, 0))),
+            hit.sum(dtype=jnp.int32))
+
+
+def _expert_ffn_hit(stacks: dict, buckets: jnp.ndarray, idx: jnp.ndarray,
+                    unit, compute_dtype):
+    """``expert_ffn`` of layer ``unit`` of the (U, E, ...) weight
+    ``stacks`` over the buckets (E, C, d), for the experts ``idx`` hits:
+    on a TPU the Pallas kernel, which reads only those experts' weights,
+    elsewhere ``expert_ffn`` over the layer's slice (its oracle).  Returns
+    the outputs, zero for experts not hit, and how many experts' weights
+    were read."""
+    e = buckets.shape[0]
+    with jax.named_scope("moe.dispatch"):
+        ids, n_hit = hit_experts(idx, e)
+    names = ("w_gate", "w_up", "w_down")
+    weights = tuple(stacks[k] for k in names)
+
+    def kernel(buckets, unit, ids, n_hit, *weights):
+        return ops.expert_ffn_hit(buckets, *weights, unit, ids, n_hit,
+                                  interpret=False), n_hit
+
+    def einsum(buckets, unit, ids, n_hit, *weights):
+        layer = {k: jax.lax.dynamic_index_in_dim(w, unit, keepdims=False)
+                 for k, w in zip(names, weights)}
+        return expert_ffn(layer, buckets, compute_dtype), jnp.int32(e)
+
+    with jax.named_scope("moe.experts"):
+        return jax.lax.platform_dependent(buckets, unit, ids, n_hit,
+                                          *weights, tpu=kernel,
+                                          default=einsum)
+
+
 @jax.named_scope("moe.shared")
 def _shared_ffn(params: dict, x: jnp.ndarray, compute_dtype) -> jnp.ndarray:
     g = jax.nn.silu(x @ params["w_gate"].astype(compute_dtype))
@@ -202,13 +255,19 @@ def _group_part(params: dict, xt: jnp.ndarray, weights: jnp.ndarray,
 
 
 def moe_apply_local(cfg: ModelConfig, params: dict, x: jnp.ndarray,
-                    compute_dtype) -> tuple[jnp.ndarray, dict]:
+                    compute_dtype, unit=None) -> tuple[jnp.ndarray, dict]:
     """Single-shard MoE: x (B, S, d) -> (B, S, d).  Test oracle + CPU path.
 
     With a held share (``cfg.n_experts_held`` < ``n_experts``) the layer is
     one device's body of expert parallelism without the exchange: it routes
     over all experts and adds only its held experts' part of the routed
     sum (group 0, the first ``n_experts_held``), plus the shared experts.
+
+    With ``unit`` (the decode layer scan, where ``reads_hit_experts``)
+    the routed experts' weights in ``params`` are the stacks over every
+    unit, (U, E, ...), and ``unit`` is this layer's index in them; only
+    the experts hit are computed (``_expert_ffn_hit``).  The aux dict's
+    ``experts_read`` counts the experts whose weights the layer read.
     """
     b, s, d = x.shape
     t = b * s
@@ -216,11 +275,17 @@ def moe_apply_local(cfg: ModelConfig, params: dict, x: jnp.ndarray,
     xt = x.reshape(t, d).astype(compute_dtype)
     weights, idx, aux = route(cfg, params["router"], xt)
     cap = capacity(cfg, t, e)
+    aux["experts_read"] = jnp.int32(cfg.experts_held)
     if cfg.held_share:
         y = _group_part(params, xt, weights, idx, 0, cap, compute_dtype)
     else:
         buckets, plan = _dispatch(xt, idx, e, cap, compute_dtype)
-        outs = expert_ffn(params, buckets.reshape(e, cap, d), compute_dtype)
+        buckets = buckets.reshape(e, cap, d)
+        if unit is None:
+            outs = expert_ffn(params, buckets, compute_dtype)
+        else:
+            outs, aux["experts_read"] = _expert_ffn_hit(
+                params, buckets, idx, unit, compute_dtype)
         y = _combine(outs.reshape(e * cap, d), plan, weights, compute_dtype)
     if cfg.n_shared_experts > 0:
         y = y + _shared_ffn(params["shared"], xt, compute_dtype)

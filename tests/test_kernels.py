@@ -5,10 +5,11 @@ import numpy as np
 import pytest
 
 from repro.kernels.mla_decode import blocks
-from repro.kernels.ops import (decode_attention, expert_ffn_pallas, gmm,
-                               mla_decode_attention)
+from repro.kernels.ops import (decode_attention, expert_ffn_hit,
+                               expert_ffn_pallas, gmm, mla_decode_attention)
 from repro.kernels.ref import decode_attention_ref, gmm_ref
 from repro.models.mla import _latent_core
+from repro.models.moe import expert_ffn, hit_experts
 
 
 def tol(dtype):
@@ -181,3 +182,58 @@ def test_mla_decode_attn_matches_latent_core(dtype, units, unit, pos,
 ])
 def test_mla_decode_blocks_from_shapes(b, s, w, want):
     assert blocks(b, s, w, 2) == want
+
+
+# --------------------------------------------------------------------- #
+# moe_decode
+# --------------------------------------------------------------------- #
+
+
+HIT_CASES = {
+    # 3 tokens x top-2 hit 3 of 8 experts: 6 slots, the last 3 padded
+    "three_hit": ([[6, 1], [4, 6], [1, 4]], [1, 4, 6, 6, 6, 6]),
+    # every copy to one expert: 5 padded slots
+    "one_hit": ([[3, 3], [3, 3], [3, 3]], [3, 3, 3, 3, 3, 3]),
+    # 6 distinct experts: no padded slot, expert 7 (the last) hit
+    "six_hit": ([[7, 0], [2, 5], [1, 4]], [0, 1, 2, 4, 5, 7]),
+}
+
+
+@pytest.mark.parametrize("case", list(HIT_CASES))
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_expert_ffn_hit_matches_expert_ffn(dtype, case):
+    """Layer ``unit`` = 2 of a 3-unit stack, 8 experts, buckets of 4: the
+    kernel gives ``expert_ffn``'s output for the experts that 3 tokens x
+    top-2 hit and zero for the others, whose buckets hold data it must
+    not read.  f32 within 1e-5 relative; in bf16 no farther from the f32
+    computation on the same inputs than ``expert_ffn`` in bf16 is (the
+    kernel keeps its gate and up products in f32, where the einsum
+    rounds them to bf16)."""
+    idx, want_ids = HIT_CASES[case]
+    u, e, c, d, f = 3, 8, 4, 128, 256
+    ks = jax.random.split(jax.random.PRNGKey(5), 4)
+    stacks = {"w_gate": jax.random.normal(ks[0], (u, e, d, f)) * 0.1,
+              "w_up": jax.random.normal(ks[1], (u, e, d, f)) * 0.1,
+              "w_down": jax.random.normal(ks[2], (u, e, f, d)) * 0.1}
+    stacks = {k: w.astype(dtype) for k, w in stacks.items()}
+    xs = jax.random.normal(ks[3], (e, c, d), dtype)
+    ids, n_hit = hit_experts(jnp.asarray(idx, jnp.int32), e)
+    np.testing.assert_array_equal(np.asarray(ids), want_ids)
+    assert int(n_hit) == len(set(want_ids))
+    out = expert_ffn_hit(xs, stacks["w_gate"], stacks["w_up"],
+                         stacks["w_down"], jnp.int32(2), ids, n_hit,
+                         interpret=True)
+    ref = expert_ffn({k: w[2] for k, w in stacks.items()}, xs, dtype)
+    assert out.shape == (e, c, d) and out.dtype == dtype
+    hit = np.isin(np.arange(e), want_ids)
+    out, ref = np.asarray(out, np.float32), np.asarray(ref, np.float32)
+    if dtype == jnp.float32:
+        np.testing.assert_allclose(out[hit], ref[hit], rtol=1e-5,
+                                   atol=1e-5 * np.abs(ref).max())
+    else:
+        exact = np.asarray(expert_ffn(
+            {k: w[2].astype(jnp.float32) for k, w in stacks.items()},
+            xs.astype(jnp.float32), jnp.float32))
+        einsum_err = np.abs(ref[hit] - exact[hit]).max()
+        assert np.abs(out[hit] - exact[hit]).max() <= einsum_err
+    assert not out[~hit].any()

@@ -7,11 +7,12 @@ import pytest
 from repro.models import (LayerSpec, ModelConfig, apply_placement,
                           decode_step, forward, init_params, loss_fn,
                           prefill, random_batch)
+from repro.models import moe
 from repro.models.attention import flash_attention
 from repro.models.config import ModelConfig as MC
 from repro.models.config import YarnScaling
 from repro.models.moe import (capacity, dispatch_indices, moe_apply_local,
-                              moe_init, route)
+                              moe_init, reads_hit_experts, route)
 
 F32 = jnp.float32
 
@@ -158,6 +159,48 @@ def test_capacity_formula():
     assert capacity(cfg, 1, 8) >= cfg.top_k
 
 
+@pytest.mark.parametrize("tokens,k,e,held,reads_hit", [
+    (8, 6, 64, 0, True),           # deepseek-moe-16b chat decode: 48 < 64
+    (32, 8, 40, 0, False),         # granite reasoning decode: 256 >= 40
+    (8 * 512, 6, 64, 0, False),    # chat prefill
+    (32 * 128, 8, 40, 0, False),   # reasoning prefill
+    (128, 6, 64, 8, False),        # deepseek-v2-lite's held share, decode
+    (1, 6, 64, 8, False),          # a held share even where T·K < E
+])
+def test_reads_hit_experts_by_shape(tokens, k, e, held, reads_hit):
+    """The decode MoE reads only the experts hit exactly where a full
+    layer's T·K copies cannot hit every expert."""
+    cfg = tiny_cfg(pattern=(LayerSpec("attn", "moe"),), n_experts=e,
+                   n_experts_held=held, top_k=k, d_ff_expert=16)
+    assert reads_hit_experts(cfg, tokens) is reads_hit
+
+
+def test_decode_reading_hit_experts_gives_the_scanned_logits(monkeypatch):
+    """A decode step whose MoE reads the expert stacks by closure (16
+    experts, 3 tokens x top-2) gives the logits and cache of the step that
+    scans the experts' weights with the other unit params."""
+    cfg = tiny_cfg(pattern=(LayerSpec("attn", "moe"),), n_layers=3,
+                   first_layer_dense=True, n_experts=16, top_k=2,
+                   d_ff_expert=16, capacity_factor=8.0)
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    b, s = 3, 8
+    assert reads_hit_experts(cfg, b)
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (b, s + 1), 0,
+                                cfg.vocab_size)
+    _, cache = prefill(cfg, params, {"tokens": tokens[:, :s]}, max_len=s + 4)
+    pos = jnp.full((b,), s, jnp.int32)
+
+    def step():
+        return jax.jit(lambda c: decode_step(
+            cfg, params, c, tokens[:, s:], pos))(cache)
+
+    got = step()
+    monkeypatch.setattr(moe, "reads_hit_experts", lambda cfg, n: False)
+    want = step()
+    jax.tree.map(lambda a, w: np.testing.assert_array_equal(
+        np.asarray(a), np.asarray(w)), got, want)
+
+
 # --------------------------------------------------------------------- #
 # Decode consistency: prefill + step == full forward
 # --------------------------------------------------------------------- #
@@ -201,6 +244,9 @@ def test_decode_matches_forward(case):
 
 TEACHER_CASES = {
     "moe": ARCH_CASES["moe"],           # one attention row written a layer
+    # 3 tokens x top-2 of 16 experts: the MoE reads the hit experts from
+    # the whole stacks at the layer's unit
+    "moe_hit_experts": dict(ARCH_CASES["moe"], n_experts=16),
     "hybrid": ARCH_CASES["hybrid"],     # + a whole mamba state at the unit
     "first_layer_dense": dict(ARCH_CASES["moe"], n_layers=3,
                               first_layer_dense=True),  # its own buffer

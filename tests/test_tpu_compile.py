@@ -25,6 +25,7 @@ from jax.sharding import SingleDeviceSharding
 from repro.kernels.decode_attn import decode_attention
 from repro.kernels.deposit import deposit
 from repro.kernels.mla_decode import mla_decode_attention
+from repro.kernels.moe_decode import expert_ffn_hit
 from repro.kernels.moe_gmm import gmm
 from repro.launch.steps import make_serve_step
 from repro.models import (LayerSpec, ModelConfig, Parallel, init_cache,
@@ -108,6 +109,18 @@ def test_mla_decode_attention_compiles_deepseek_v2_lite(one_chip):
     pos = _struct((128,), jnp.int32, one_chip)
     _assert_mosaic(functools.partial(mla_decode_attention, scale=0.1),
                    q, stack, unit, pos)
+
+
+def test_expert_ffn_hit_compiles_deepseek_moe_16b(one_chip):
+    """DeepSeek-MoE-16B chat decode: the hit experts of a 10-layer stack
+    of 64 experts (d 2048, d_ff 1408), buckets of 8, 48 slots, whole
+    experts a grid step within the chip's fast memory."""
+    xs = _struct((64, 8, 2048), jnp.bfloat16, one_chip)
+    w = _struct((10, 64, 2048, 1408), jnp.bfloat16, one_chip)
+    wd = _struct((10, 64, 1408, 2048), jnp.bfloat16, one_chip)
+    unit = _struct((), jnp.int32, one_chip)
+    ids = _struct((48,), jnp.int32, one_chip)
+    _assert_mosaic(expert_ffn_hit, xs, w, w, wd, unit, ids, unit)
 
 
 # Decode widths of the benchmark's serving cells, at a few layers: the
