@@ -38,7 +38,8 @@ def attn_init(key, cfg: ModelConfig, dtype) -> dict:
 
 @jax.named_scope("attn.qkv")
 def _project_qkv(cfg: ModelConfig, params, x, positions, compute_dtype):
-    """x: (B, S, d) -> q (B,S,Hq,hd), k/v (B,S,Hkv,hd), with RoPE."""
+    """x: (B, S, d) -> q (B,S,Hq,hd), k/v (B,S,Hkv,hd), with RoPE unless
+    the model has no position embedding (``cfg.nope``)."""
     b, s, _ = x.shape
     x = x.astype(compute_dtype)
     q = x @ params["w_q"].astype(compute_dtype)
@@ -51,9 +52,16 @@ def _project_qkv(cfg: ModelConfig, params, x, positions, compute_dtype):
     q = q.reshape(b, s, cfg.n_heads, cfg.head_dim)
     k = k.reshape(b, s, cfg.n_kv_heads, cfg.head_dim)
     v = v.reshape(b, s, cfg.n_kv_heads, cfg.head_dim)
-    q = apply_rope(q, positions, cfg.rope_theta)
-    k = apply_rope(k, positions, cfg.rope_theta)
+    if not cfg.nope:
+        q = apply_rope(q, positions, cfg.rope_theta)
+        k = apply_rope(k, positions, cfg.rope_theta)
     return q, k, v
+
+
+def softmax_scale(cfg: ModelConfig) -> float:
+    """``attention_multiplier`` where the model sets one, else
+    head_dim^-0.5."""
+    return cfg.attention_multiplier or cfg.head_dim ** -0.5
 
 
 def _softcap(s, cap: float):
@@ -284,7 +292,8 @@ def attention_forward(
 ) -> jnp.ndarray:
     """Training / prefill self-attention (no cache returned)."""
     q, k, v = _project_qkv(cfg, params, x, positions, compute_dtype)
-    out = flash_attention(cfg, q, k, v, positions, positions)
+    out = flash_attention(cfg, q, k, v, positions, positions,
+                          softmax_scale(cfg))
     b, s = x.shape[:2]
     with jax.named_scope("attn.out"):
         return out.reshape(b, s, cfg.q_dim) @ params["w_o"].astype(compute_dtype)
@@ -296,7 +305,8 @@ def attention_prefill(
 ) -> tuple[jnp.ndarray, dict]:
     """Prefill: run causal attention AND write K/V into the cache at [0, S)."""
     q, k, v = _project_qkv(cfg, params, x, positions, compute_dtype)
-    out = flash_attention(cfg, q, k, v, positions, positions)
+    out = flash_attention(cfg, q, k, v, positions, positions,
+                          softmax_scale(cfg))
     with jax.named_scope("attn.kv_write"):
         new_cache = {
             "k": jax.lax.dynamic_update_slice(
@@ -375,11 +385,11 @@ def _write_rows(cache: jnp.ndarray, rows: jnp.ndarray, pos: jnp.ndarray,
 @jax.named_scope("attn.core")
 def _decode_core(cfg: ModelConfig, qg, ck, cv, pos, compute_dtype):
     """One query per sequence over its cache: (B, Hkv, G, hd) -> same."""
-    s_max, hd = ck.shape[1], cfg.head_dim
+    s_max = ck.shape[1]
     kt = ck.astype(compute_dtype)
     vt = cv.astype(compute_dtype)
     sco = jnp.einsum("bngd,bsnd->bngs", qg, kt,
-                     preferred_element_type=jnp.float32) * (hd ** -0.5)
+                     preferred_element_type=jnp.float32) * softmax_scale(cfg)
     sco = _softcap(sco, cfg.attn_logit_softcap)
     kv_pos = jnp.arange(s_max)[None, :]                        # (1, S)
     mask = kv_pos <= pos[:, None]

@@ -16,7 +16,7 @@ import dataclasses
 class LayerSpec:
     """One block of the repeating pattern."""
 
-    mixer: str = "attn"     # "attn" | "mla" | "mamba" | "mlstm" | "slstm"
+    mixer: str = "attn"     # "attn" | "mla" | "mamba" | "mamba2" | "mlstm" | "slstm"
     ffn: str = "dense"      # "dense" | "moe" | "none"
 
 
@@ -31,6 +31,10 @@ class YarnScaling:
     beta_slow: float = 1.0
     mscale: float = 1.0
     mscale_all_dim: float = 0.0
+
+
+#: Mixers that carry O(1) decode state in place of a KV cache.
+RECURRENT = ("mamba", "mamba2", "mlstm", "slstm")
 
 
 def _round_up(x: int, m: int) -> int:
@@ -66,6 +70,8 @@ class ModelConfig:
     attn_logit_softcap: float = 0.0
     sliding_window: int = 0              # 0 => full causal
     rope_scaling: YarnScaling | None = None
+    nope: bool = False                   # no position embedding (NoPE):
+    #   attention without rotary, as ``position_embedding_type`` "nope"
 
     # --- latent attention (mixer "mla", DeepSeek-V2) ---
     kv_lora_rank: int = 0                # latent width cached per token
@@ -78,6 +84,18 @@ class ModelConfig:
     mamba_expand: int = 2
     mamba_d_conv: int = 4
     mamba_dt_rank: int = 0               # 0 => ceil(d_model / 16)
+    # Mamba-2 (mixer "mamba2"): d_inner = mamba_n_heads * mamba_head_dim;
+    # B and C are shared by the heads of each of mamba_n_groups groups.
+    mamba_n_heads: int = 0
+    mamba_head_dim: int = 0
+    mamba_n_groups: int = 1
+    mamba_chunk_size: int = 256          # SSD chunk (prefill / train)
+
+    # --- GraniteMoE multipliers (the defaults leave a model unscaled) ---
+    embedding_multiplier: float = 1.0    # on the input embeddings
+    residual_multiplier: float = 1.0     # on each block's mixer and FFN out
+    attention_multiplier: float = 0.0    # softmax scale; 0 => head_dim^-0.5
+    logits_scaling: float = 1.0          # the logits are divided by it
 
     # --- embeddings / head ---
     tie_embeddings: bool = False
@@ -123,6 +141,13 @@ class ModelConfig:
             raise ValueError(f"{self.name}: the mla mixer needs kv_lora_rank, "
                              "the nope, rope and v head sizes and YaRN "
                              "rope_scaling")
+        if any(s.mixer == "mamba2" for s in self.pattern) and not (
+                self.mamba_n_heads * self.mamba_head_dim == self.d_inner
+                and self.mamba_n_heads % self.mamba_n_groups == 0):
+            raise ValueError(
+                f"{self.name}: the mamba2 mixer needs mamba_n_heads x "
+                f"mamba_head_dim = d_inner ({self.d_inner}) and heads that "
+                f"divide into mamba_n_groups ({self.mamba_n_groups})")
 
     # ------------------------------------------------------------------ #
     @property
@@ -161,19 +186,24 @@ class ModelConfig:
         return self.mamba_expand * self.d_model
 
     @property
+    def mamba_conv_dim(self) -> int:
+        """Mamba-2's convolved channels: x, then B and C of every group."""
+        return self.d_inner + 2 * self.mamba_n_groups * self.mamba_d_state
+
+    @property
     def has_moe(self) -> bool:
         return any(s.ffn == "moe" for s in self.pattern)
 
     @property
     def is_recurrent(self) -> bool:
         """True if every mixer carries O(1) decode state (no KV growth)."""
-        return all(s.mixer in ("mamba", "mlstm", "slstm") for s in self.pattern)
+        return all(s.mixer in RECURRENT for s in self.pattern)
 
     @property
     def supports_long_context(self) -> bool:
         """Sub-quadratic prefill / O(1)-ish decode state per the assignment:
         SSM / hybrid archs run long_500k; pure full-attention archs skip."""
-        return any(s.mixer in ("mamba", "mlstm", "slstm") for s in self.pattern)
+        return any(s.mixer in RECURRENT for s in self.pattern)
 
     # -- parameter counting (for roofline MODEL_FLOPS = 6*N*D) ----------- #
     def param_counts(self) -> dict[str, float]:
@@ -201,6 +231,10 @@ class ModelConfig:
                     + d * self.latent_dim + r \
                     + r * h * (self.qk_nope_head_dim + self.v_head_dim) \
                     + h * self.v_head_dim * d
+            elif spec.mixer == "mamba2":
+                h, cd = self.mamba_n_heads, self.mamba_conv_dim
+                p = d * (self.d_inner + cd + h) + cd * (self.mamba_d_conv + 1) \
+                    + 3 * h + self.d_inner + self.d_inner * d
             elif spec.mixer == "mamba":
                 di = self.d_inner
                 p = d * 2 * di + di * self.mamba_d_conv \

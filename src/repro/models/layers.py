@@ -21,6 +21,13 @@ STAGES = (
 #: taken into and outputs out of latent space (decode), and the scores and
 #: weighted sum over the latent cache (decode).
 MLA_SCOPES = ("mla.expand", "mla.absorb", "mla.latent")
+#: Scopes of the Mamba-2 mixer (``repro.models.ssm``): the input
+#: projection, the causal convolution, the chunked SSD over a prompt
+#: (prefill and train), the one-step state update and read-out (decode,
+#: the state read from and written to the layer scan's stack included) and
+#: the gated norm with the output projection.  They lie in no stage: in
+#: the decode scan the stage readers count their ops under ``layers``.
+SSM_SCOPES = ("ssm.proj", "ssm.conv", "ssm.ssd", "ssm.state", "ssm.out")
 
 
 def dtype_of(name: str):

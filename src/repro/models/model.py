@@ -3,7 +3,7 @@
 The layer stack is a ``lax.scan`` over repeating pattern units (HLO size
 independent of depth).  Each unit applies its pattern of
 (mixer, ffn) blocks; mixers are attention / latent attention (mla) /
-mamba / mlstm / slstm, FFNs
+mamba / mamba2 / mlstm / slstm, FFNs
 are dense SwiGLU or MoE.  Decode carries a per-unit cache pytree (KV cache
 for attention, recurrent state for SSM blocks) stacked along the unit axis
 through the scan; each block writes only its own rows of it.
@@ -69,6 +69,8 @@ def _block_init(key, cfg: ModelConfig, spec: LayerSpec, dtype) -> dict:
         p["mixer"] = mla.mla_init(km, cfg, dtype)
     elif spec.mixer == "mamba":
         p["mixer"] = ssm.mamba_init(km, cfg, dtype)
+    elif spec.mixer == "mamba2":
+        p["mixer"] = ssm.mamba2_init(km, cfg, dtype)
     elif spec.mixer == "mlstm":
         p["mixer"] = ssm.mlstm_init(km, cfg, dtype)
     elif spec.mixer == "slstm":
@@ -134,6 +136,8 @@ def _apply_mixer(cfg, spec, bp, x, positions, par, cdt, cache, mode,
     new K/V rows into the stack, a recurrent block its whole state at
     ``unit``.  The stack comes back updated in place.  Latent attention
     (``mla``) writes its latent rows the way attention writes K/V.
+    Mamba-2 runs the chunked SSD over a prompt (prefill starts every
+    sequence at position 0) and one recurrence step in decode.
     """
     if spec.mixer == "attn":
         if mode == "train":
@@ -148,6 +152,11 @@ def _apply_mixer(cfg, spec, bp, x, positions, par, cdt, cache, mode,
         if mode == "prefill":
             return mla.mla_prefill(cfg, bp["mixer"], x, positions, cache, cdt)
         return mla.mla_decode(cfg, bp["mixer"], x, positions, cache, cdt, unit)
+    if spec.mixer == "mamba2":
+        if mode == "decode":
+            return ssm.mamba2_decode(cfg, bp["mixer"], x, cache, cdt, unit)
+        y, state = ssm.mamba2_forward(cfg, bp["mixer"], x, cdt)
+        return (y, None) if mode == "train" else (y, state)
     if spec.mixer in ("mamba", "mlstm"):
         fwd, dec = {"mamba": (ssm.mamba_forward, ssm.mamba_decode),
                     "mlstm": (ssm.mlstm_forward, ssm.mlstm_decode)}[spec.mixer]
@@ -218,15 +227,40 @@ def _apply_block(cfg, spec, bp, x, positions, par, cdt, cache, mode,
     h = rmsnorm(bp["norm1"], x, cfg.norm_eps)
     y, new_cache = _apply_mixer(cfg, spec, bp, h, positions, par, cdt, cache,
                                 mode, unit)
-    x = x + y
+    x = x + _residual(cfg, y)
     if spec.ffn != "none":
         h = rmsnorm(bp["norm2"], x, cfg.norm_eps)
         if spec.ffn == "dense":
-            x = x + ffn_apply(bp["ffn"], h, cdt)
+            x = x + _residual(cfg, ffn_apply(bp["ffn"], h, cdt))
         else:
             out, aux = _apply_moe(cfg, bp["ffn"], h, par, cdt, moe_unit)
-            x = x + out
+            x = x + _residual(cfg, out)
     return x, new_cache, aux
+
+
+def _scaled(x, m: float):
+    """``x`` times a GraniteMoE multiplier, in f32 and rounded once to
+    x's dtype; ``x`` itself where the multiplier is 1."""
+    return x if m == 1.0 else (x.astype(jnp.float32) * m).astype(x.dtype)
+
+
+def _residual(cfg, y):
+    """A block's output as added to the residual stream."""
+    return _scaled(y, cfg.residual_multiplier)
+
+
+def _embed_tokens(cfg, params, tokens, cdt):
+    x = embed(params["embed"], tokens, cdt)
+    with jax.named_scope("embed"):
+        return _scaled(x, cfg.embedding_multiplier)
+
+
+def _logits(cfg, params, x):
+    """The head over the final-normed ``x``, over ``logits_scaling``."""
+    table = params["embed"] if cfg.tie_embeddings else params["head"]
+    logits = lm_head(table, x, cfg.tie_embeddings)
+    with jax.named_scope("head"):
+        return _scaled(logits, 1.0 / cfg.logits_scaling)
 
 
 def _apply_unit(cfg, unit_params, x, positions, par, cdt, unit_cache, mode,
@@ -266,7 +300,7 @@ def _embed_inputs(cfg, params, batch, cdt):
     if "embeds" in batch:
         parts.append(batch["embeds"].astype(cdt))
     if "tokens" in batch:
-        parts.append(embed(params["embed"], batch["tokens"], cdt))
+        parts.append(_embed_tokens(cfg, params, batch["tokens"], cdt))
     x = parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=1)
     b, s = x.shape[0], x.shape[1]
     positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32)[None], (b, s))
@@ -303,8 +337,7 @@ def forward(cfg: ModelConfig, params: dict, batch: dict,
         (x, aux_total), counts = jax.lax.scan(body, (x, aux_total),
                                               params["units"])
     x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
-    table = params["embed"] if cfg.tie_embeddings else params["head"]
-    logits = lm_head(table, x, cfg.tie_embeddings)
+    logits = _logits(cfg, params, x)
     if return_router_stats:
         return logits, aux_total, counts
     return logits, aux_total
@@ -341,6 +374,8 @@ def _block_cache(cfg, spec: LayerSpec, batch: int, max_len: int, cdt):
         return mla.init_latent_cache(cfg, batch, max_len, cdt)
     if spec.mixer == "mamba":
         return ssm.mamba_init_state(cfg, batch)
+    if spec.mixer == "mamba2":
+        return ssm.mamba2_init_state(cfg, batch)
     if spec.mixer == "mlstm":
         return ssm.mlstm_init_state(cfg, batch)
     if spec.mixer == "slstm":
@@ -401,7 +436,7 @@ def decode_step(cfg: ModelConfig, params: dict, cache: dict,
     if embeds is not None:
         x = embeds.astype(cdt)
     else:
-        x = embed(params["embed"], tokens, cdt)
+        x = _embed_tokens(cfg, params, tokens, cdt)
     new_cache: dict = {}
     if cfg.first_layer_dense:
         x, fc, _ = _apply_block(
@@ -428,8 +463,7 @@ def decode_step(cfg: ModelConfig, params: dict, cache: dict,
             unit_step, (x, cache["units"]), (unit_params, units)
         )
     x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
-    table = params["embed"] if cfg.tie_embeddings else params["head"]
-    logits = lm_head(table, x, cfg.tie_embeddings)
+    logits = _logits(cfg, params, x)
     return logits[:, 0, :], new_cache
 
 
@@ -464,6 +498,5 @@ def prefill(cfg: ModelConfig, params: dict, batch: dict, max_len: int,
         )
     new_cache["units"] = new_unit_caches
     x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
-    table = params["embed"] if cfg.tie_embeddings else params["head"]
-    logits = lm_head(table, x[:, -1:, :], cfg.tie_embeddings)
+    logits = _logits(cfg, params, x[:, -1:, :])
     return logits[:, 0, :], new_cache

@@ -1,23 +1,31 @@
-"""Recurrent mixers: Mamba (selective SSM), mLSTM and sLSTM (xLSTM).
+"""Recurrent mixers: Mamba (selective SSM), Mamba-2 (state-space duality),
+mLSTM and sLSTM (xLSTM).
 
-All three expose the same triplet of entry points:
+All of them expose the same triplet of entry points:
   *_init(key, cfg, dtype)                  -> params
   *_forward(cfg, params, x, compute_dtype) -> y          (train/prefill)
   *_init_state / *_decode(...)             -> O(1) decode state + step
 
-Sequence processing uses ``lax.scan`` over time — correct and HLO-compact;
-the per-step state is exactly the decode state, so prefill and decode
-cannot drift apart.  These mixers carry no KV cache, which is what makes
-``long_500k`` decode feasible for jamba/xlstm.
+Mamba-1 and the xLSTM blocks process a sequence with ``lax.scan`` over
+time — correct and HLO-compact; the per-step state is exactly the decode
+state, so prefill and decode cannot drift apart.  Mamba-2 processes a
+sequence by the chunked SSD algorithm (matrix products within a chunk, a
+recurrence over chunk states) and decodes by the per-token recurrence,
+which the chunks compute exactly.  These mixers carry no KV cache, which
+is what makes ``long_500k`` decode feasible for jamba/xlstm.
 """
 from __future__ import annotations
+
+import math
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from .config import ModelConfig
-from .layers import normal_init, out_proj_init
+from .layers import normal_init, out_proj_init, rmsnorm_init
+
+F32 = jnp.float32
 
 
 def _pin(x, par, *dims):
@@ -45,6 +53,20 @@ def _pin(x, par, *dims):
         else:
             spec.append(None)
     return jax.lax.with_sharding_constraint(x, P(*spec))
+
+
+def _causal_conv(x, conv_state, w, b):
+    """Causal depthwise convolution along S, continuing from the carried
+    inputs, then SiLU.  x (B, S, C); conv_state (B, dc-1, C), the dc-1
+    inputs before x; w (dc, C); b (C,).  Returns (silu(conv(x) + b),
+    the last dc-1 inputs), in x's dtype."""
+    s, dc = x.shape[1], w.shape[0]
+    xpad = jnp.concatenate([conv_state.astype(x.dtype), x], axis=1)
+    w = w.astype(x.dtype)
+    y = sum(xpad[:, i : i + s, :] * w[i] for i in range(dc))
+    y = jax.nn.silu(y + b.astype(x.dtype))
+    return y, (xpad[:, s:, :] if dc > 1 else conv_state)
+
 
 # ===================================================================== #
 # Mamba (selective state-space, Mamba-1)
@@ -86,17 +108,11 @@ def _mamba_inner(cfg, params, xz, conv_state, ssm_state, compute_dtype,
     conv_state: (B, dc-1, di) trailing inputs; ssm_state: (B, di, N).
     Returns (y (B,S,di), new_conv_state, new_ssm_state).
     """
-    di, n = cfg.d_inner, cfg.mamba_d_state
+    n = cfg.mamba_d_state
     x, z = jnp.split(xz, 2, axis=-1)                          # (B, S, di)
-    b, s, _ = x.shape
-    dc = cfg.mamba_d_conv
 
-    # Causal depthwise conv along S with carried state.
-    xpad = jnp.concatenate([conv_state.astype(x.dtype), x], axis=1)
-    conv_w = params["conv_w"].astype(x.dtype)                 # (dc, di)
-    xc = sum(xpad[:, i : i + s, :] * conv_w[i] for i in range(dc))
-    xc = jax.nn.silu(xc + params["conv_b"].astype(x.dtype))
-    new_conv_state = xpad[:, s:, :] if dc > 1 else conv_state
+    xc, new_conv_state = _causal_conv(x, conv_state, params["conv_w"],
+                                      params["conv_b"])
     xc = _pin(xc, par, "b", None, "m")
 
     proj = xc @ params["w_x_proj"].astype(x.dtype)            # (B,S,r+2N)
@@ -154,6 +170,196 @@ def mamba_decode(cfg, params, x, state, compute_dtype, par=None):
     y, conv, ssm = _mamba_inner(cfg, params, xz, state["conv"], state["ssm"],
                                 compute_dtype, par)
     return y @ params["w_out"].astype(compute_dtype), {"conv": conv, "ssm": ssm}
+
+
+# ===================================================================== #
+# Mamba-2 (state-space duality, arXiv:2405.21060)
+# ===================================================================== #
+#
+# For a normed input u (the layout of the published checkpoints):
+#   [z | xBC | dt] = u w_in;  xBC = silu(causal conv(xBC) + conv_b);
+#   [x | B | C] = xBC;  dt = softplus(dt + dt_bias);  A = -exp(A_log);
+#   per head h of group g:  S_t = exp(dt_t A_h) S_{t-1} + dt_t x_t B_t^T,
+#                           y_t = S_t C_t + D_h x_t;
+#   out = rmsnorm(y * silu(z)) w_out.
+# S is (head_dim, d_state) per head and stays float32, as does the
+# convolution and everything between it and the gated norm.
+
+
+def mamba2_init(key, cfg: ModelConfig, dtype) -> dict:
+    """Mamba-2's published initialisation for the SSM's scalars: A uniform
+    in [1, 16], dt log-uniform in [0.001, 0.1] (``dt_bias`` its inverse
+    softplus), D = 1."""
+    d, di, h = cfg.d_model, cfg.d_inner, cfg.mamba_n_heads
+    cd = cfg.mamba_conv_dim
+    ks = jax.random.split(key, 5)
+    a = jax.random.uniform(ks[2], (h,), F32, 1.0, 16.0)
+    dt = jnp.exp(jax.random.uniform(ks[3], (h,), F32, math.log(1e-3),
+                                    math.log(0.1)))
+    return {
+        "w_in": normal_init(ks[0], (d, di + cd + h), dtype),
+        "conv_w": normal_init(ks[1], (cfg.mamba_d_conv, cd), dtype, scale=0.1),
+        "conv_b": jnp.zeros((cd,), dtype),
+        "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
+        "A_log": jnp.log(a),
+        "D": jnp.ones((h,), F32),
+        "norm": rmsnorm_init(di, F32),
+        "w_out": out_proj_init(ks[4], (di, d), dtype, cfg.n_layers),
+    }
+
+
+def mamba2_init_state(cfg: ModelConfig, batch: int) -> dict:
+    """The last d_conv - 1 convolution inputs and the SSM state, f32."""
+    return {
+        "conv": jnp.zeros((batch, cfg.mamba_d_conv - 1, cfg.mamba_conv_dim),
+                          F32),
+        "ssm": jnp.zeros((batch, cfg.mamba_n_heads, cfg.mamba_head_dim,
+                          cfg.mamba_d_state), F32),
+    }
+
+
+def _mamba2_in(cfg: ModelConfig, params, x, compute_dtype):
+    """x (B, S, d) -> gate z (B, S, di), xBC (B, S, conv_dim) f32 and the
+    time steps dt (B, S, H) f32 (softplus of the projection + dt_bias)."""
+    di, cd = cfg.d_inner, cfg.mamba_conv_dim
+    with jax.named_scope("ssm.proj"):
+        zxbcdt = x.astype(compute_dtype) @ params["w_in"].astype(compute_dtype)
+        z, xbc, dt = jnp.split(zxbcdt, [di, di + cd], axis=-1)
+        dt = jax.nn.softplus(dt.astype(F32) + params["dt_bias"])
+    return z, xbc.astype(F32), dt
+
+
+def _split_xbc(cfg: ModelConfig, xbc):
+    """(B, S, conv_dim) -> x (B, S, H, P), B and C (B, S, G, N)."""
+    b, s = xbc.shape[:2]
+    di, g, n = cfg.d_inner, cfg.mamba_n_groups, cfg.mamba_d_state
+    x, bm, cm = jnp.split(xbc, [di, di + g * n], axis=-1)
+    return (x.reshape(b, s, cfg.mamba_n_heads, cfg.mamba_head_dim),
+            bm.reshape(b, s, g, n), cm.reshape(b, s, g, n))
+
+
+def _mamba2_out(cfg: ModelConfig, params, y, z, compute_dtype):
+    """Gated RMSNorm over d_inner, then the output projection: y (B, S,
+    H, P) f32, z (B, S, di) -> (B, S, d)."""
+    b, s = z.shape[:2]
+    with jax.named_scope("ssm.out"):
+        y = y.reshape(b, s, cfg.d_inner) * jax.nn.silu(z.astype(F32))
+        y = y * jax.lax.rsqrt(jnp.mean(y * y, axis=-1, keepdims=True)
+                              + cfg.norm_eps) * params["norm"]["scale"]
+        return y.astype(compute_dtype) @ params["w_out"].astype(compute_dtype)
+
+
+def ssd(x, dt, a, bm, cm, chunk: int):
+    """The SSM over a sequence by the chunked state-space-duality algorithm,
+    in float32: within each chunk of ``chunk`` positions the outputs are
+    matrix products over a causal decay mask, and one recurrence over the
+    chunks carries the state from each chunk to the next.
+
+    x (B, S, H, P), dt (B, S, H), a (H,) (negative), bm and cm (B, S, G,
+    N), from a zero state.  Returns y (B, S, H, P) without the D skip, and
+    the state (B, H, P, N) after the last position.  A length that is not
+    a multiple of the chunk is padded with dt = 0, which neither decays the
+    state nor adds to it."""
+    b, s, h, p = x.shape
+    g, n = bm.shape[2:]
+    q = min(chunk, s)
+    nc = -(-s // q)
+    if nc * q > s:
+        def pad(v):
+            return jnp.pad(v, [(0, 0), (0, nc * q - s)] + [(0, 0)] * (v.ndim - 2))
+        x, dt, bm, cm = pad(x), pad(dt), pad(bm), pad(cm)
+    hg = h // g
+
+    def chunks(v):                     # (B, nc q, ...) -> (nc, B, q, ...)
+        return v.reshape(b, nc, q, *v.shape[2:]).swapaxes(0, 1)
+
+    xdt = chunks((x * dt[..., None]).reshape(b, nc * q, g, hg, p))
+    log_a = chunks((dt * a).reshape(b, nc * q, g, hg))
+    causal = jnp.tril(jnp.ones((q, q), bool))[None, :, :, None, None]
+    hi = jax.lax.Precision.HIGHEST
+
+    def chunk_step(st, inp):
+        # st (B, G, Hg, P, N); xc (B, q, G, Hg, P); la (B, q, G, Hg);
+        # bc, cc (B, q, G, N)
+        xc, la, bc, cc = inp
+        cum = jnp.cumsum(la, axis=1)
+        # decay from position s to l >= s of the chunk: exp(cum_l - cum_s)
+        seg = cum[:, :, None] - cum[:, None]
+        decay = jnp.exp(jnp.where(causal, seg, -jnp.inf))  # (B, l, s, G, Hg)
+        cb = jnp.einsum("blgn,bsgn->blsg", cc, bc, precision=hi)
+        y = jnp.einsum("blsgh,bsghp->blghp", cb[..., None] * decay, xc,
+                       precision=hi)
+        y = y + jnp.einsum("blgn,bghpn->blghp", cc, st, precision=hi) \
+            * jnp.exp(cum)[..., None]
+        tail = jnp.exp(cum[:, -1:] - cum)[..., None]     # to the chunk's end
+        st = st * jnp.exp(cum[:, -1])[..., None, None] + jnp.einsum(
+            "bsghp,bsgn->bghpn", xc * tail, bc, precision=hi)
+        return st, y
+
+    st, ys = jax.lax.scan(chunk_step, jnp.zeros((b, g, hg, p, n), F32),
+                          (xdt, log_a, chunks(bm), chunks(cm)))
+    y = ys.swapaxes(0, 1).reshape(b, nc * q, h, p)[:, :s]
+    return y, st.reshape(b, h, p, n)
+
+
+def ssm_step(st, x, dt, a, bm, cm):
+    """One position of the recurrence: st (B, H, P, N), x (B, H, P), dt
+    (B, H), a (H,), bm and cm (B, G, N), f32.  Returns (S_t, S_t C_t)."""
+    rep = st.shape[1] // bm.shape[1]
+    bh = jnp.repeat(bm, rep, axis=1)[:, :, None, :]              # (B,H,1,N)
+    ch = jnp.repeat(cm, rep, axis=1)[:, :, None, :]
+    st = jnp.exp(dt * a)[..., None, None] * st \
+        + (dt[..., None] * x)[..., None] * bh
+    return st, jnp.sum(st * ch, axis=-1)
+
+
+def mamba2_forward(cfg: ModelConfig, params: dict, x, compute_dtype):
+    """Train and prefill: x (B, S, d), every sequence from position 0.
+    Returns (y (B, S, d), the decode state after the last position)."""
+    b = x.shape[0]
+    z, xbc, dt = _mamba2_in(cfg, params, x, compute_dtype)
+    st0 = mamba2_init_state(cfg, b)
+    with jax.named_scope("ssm.conv"):
+        xbc, conv = _causal_conv(xbc, st0["conv"], params["conv_w"],
+                                 params["conv_b"])
+    xh, bm, cm = _split_xbc(cfg, xbc)
+    with jax.named_scope("ssm.ssd"):
+        y, st = ssd(xh, dt, -jnp.exp(params["A_log"]), bm, cm,
+                    cfg.mamba_chunk_size)
+        y = y + params["D"][:, None] * xh
+    return (_mamba2_out(cfg, params, y, z, compute_dtype),
+            {"conv": conv, "ssm": st})
+
+
+def mamba2_decode(cfg: ModelConfig, params: dict, x, state: dict,
+                  compute_dtype, unit=None):
+    """One-token decode: x (B, 1, d) -> (y (B, 1, d), new state).
+
+    ``state`` holds this layer's conv and SSM state, or with ``unit`` the
+    stacks over the layer scan's units and this layer's index in them:
+    the layer's state is read from the stacks and the new state written
+    back at ``unit``, so the stacks come back updated in place."""
+    def read(a):
+        return a if unit is None else jax.lax.dynamic_index_in_dim(
+            a, unit, keepdims=False)
+
+    def write(a, v):
+        return v if unit is None else jax.lax.dynamic_update_index_in_dim(
+            a, v, unit, 0)
+
+    z, xbc, dt = _mamba2_in(cfg, params, x, compute_dtype)
+    with jax.named_scope("ssm.conv"):
+        xbc, conv = _causal_conv(xbc, read(state["conv"]), params["conv_w"],
+                                 params["conv_b"])
+        conv = write(state["conv"], conv)
+    xh, bm, cm = _split_xbc(cfg, xbc)
+    with jax.named_scope("ssm.state"):
+        st, y = ssm_step(read(state["ssm"]), xh[:, 0], dt[:, 0],
+                         -jnp.exp(params["A_log"]), bm[:, 0], cm[:, 0])
+        y = (y + params["D"][:, None] * xh[:, 0])[:, None]
+        st = write(state["ssm"], st)
+    return (_mamba2_out(cfg, params, y, z, compute_dtype),
+            {"conv": conv, "ssm": st})
 
 
 # ===================================================================== #

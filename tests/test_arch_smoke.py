@@ -15,8 +15,9 @@ ALL_ARCHS = list_archs()
 
 def test_registry_complete():
     assert len(ASSIGNED) == 10
-    # + the paper's llama-moe-3.5b and deepseek-v2-lite (latent attention)
-    assert len(ALL_ARCHS) == 12
+    # + the paper's llama-moe-3.5b, deepseek-v2-lite (latent attention) and
+    # granite-4.0-h-small (Mamba-2 beside attention)
+    assert len(ALL_ARCHS) == 13
 
 
 @pytest.mark.parametrize("arch", ALL_ARCHS)
@@ -44,6 +45,7 @@ def test_param_counts_match_public_sizes():
         "xlstm-350m": (350e6, 0.35),
         "llama-moe-3.5b": (6.7e9, 0.15),
         "deepseek-v2-lite": (15.7e9, 0.05),
+        "granite-4.0-h-small": (32e9, 0.05),
     }
     for arch, (target, tol) in expect.items():
         total = get_config(arch).param_counts()["total"]
@@ -60,6 +62,9 @@ def test_active_params():
     # deepseek-v2-lite: 2.4B activated, with embedding and head 2.66B
     a = get_config("deepseek-v2-lite").param_counts()["active"]
     assert 2.4e9 < a < 2.8e9
+    # granite-4.0-h-small: 32B-A9B
+    a = get_config("granite-4.0-h-small").param_counts()["active"]
+    assert 8.5e9 < a < 9.5e9
 
 
 @pytest.mark.slow

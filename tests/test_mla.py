@@ -128,37 +128,47 @@ def test_pairs_rope_is_the_half_rope_of_deinterleaved_dims():
 # --------------------------------------------------------------------- #
 
 
-def test_held_shares_add_up_to_the_whole_layer():
-    """A layer of 8 experts held 2 at a time by 4 chips: with the shared
-    expert, which every chip computes alike, counted once, the 4 shares'
-    outputs add up to the uncut layer's."""
-    whole = dataclasses.replace(CFG, n_experts_held=0)
-    held = dataclasses.replace(CFG, n_experts_held=2)
+@pytest.mark.parametrize("n_experts,held,top_k,shared", [
+    (8, 2, 2, 1),       # 4 chips of 2 experts
+    (72, 9, 10, 2),     # granite-4.0-h-small's split: 8 chips of 9, top-10
+])
+def test_held_shares_add_up_to_the_whole_layer(n_experts, held, top_k,
+                                               shared):
+    """A layer's experts held ``held`` at a time by E / held chips: with
+    the shared experts, which every chip computes alike, counted once,
+    the shares' outputs add up to the uncut layer's."""
+    whole = dataclasses.replace(CFG, n_experts=n_experts, n_experts_held=0,
+                                top_k=top_k, n_shared_experts=shared,
+                                capacity_factor=n_experts / top_k)
+    part = dataclasses.replace(whole, n_experts_held=held)
+    chips = n_experts // held
     params = moe_init(jax.random.PRNGKey(5), whole, F32)
     x = jax.random.normal(jax.random.PRNGKey(6), (2, 10, CFG.d_model), F32)
     with jax.default_matmul_precision("highest"):
         want, _ = moe_apply_local(whole, params, x, F32)
         parts = []
-        for g in range(4):
-            # Chip g holds experts 2g, 2g+1: as the program's held share is
-            # the first experts of the router, its columns are rotated so
-            # that those come first (the routing itself does not change).
+        for g in range(chips):
+            # Chip g holds experts [g held, (g + 1) held): as the program's
+            # held share is the first experts of the router, its columns
+            # are rotated so that those come first (the routing itself does
+            # not change).
             share = dict(params,
-                         router=jnp.roll(params["router"], -2 * g, axis=1),
-                         **{n: params[n][2 * g:2 * g + 2]
+                         router=jnp.roll(params["router"], -held * g, axis=1),
+                         **{n: params[n][held * g:held * (g + 1)]
                             for n in ("w_gate", "w_up", "w_down")})
-            y, aux = moe_apply_local(held, share, x, F32)
+            y, aux = moe_apply_local(part, share, x, F32)
             parts.append(y)
         sh = params["shared"]
         xt = x.reshape(-1, CFG.d_model)
-        shared = ((jax.nn.silu(xt @ sh["w_gate"]) * (xt @ sh["w_up"]))
-                  @ sh["w_down"]).reshape(x.shape)
-    got, want = np.asarray(sum(parts) - 3 * shared), np.asarray(want)
+        shared_out = ((jax.nn.silu(xt @ sh["w_gate"]) * (xt @ sh["w_up"]))
+                      @ sh["w_down"]).reshape(x.shape)
+    got = np.asarray(sum(parts) - (chips - 1) * shared_out)
+    want = np.asarray(want)
     scale = np.abs(want).max()
     # Float32 at full precision: the shares and the whole layer sum the
     # same products in another order, to a few ulps of the output's scale.
     np.testing.assert_allclose(got, want, atol=1e-5 * scale)
-    assert aux["expert_counts"].shape == (8,)
+    assert aux["expert_counts"].shape == (n_experts,)
     # No single share is the whole layer.
     assert np.abs(np.asarray(parts[0]) - want).max() > 0.1 * scale
 

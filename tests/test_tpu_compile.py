@@ -126,7 +126,8 @@ def test_expert_ffn_hit_compiles_deepseek_moe_16b(one_chip):
 # Decode widths of the benchmark's serving cells, at a few layers: the
 # cache plumbing of the layer scan does not depend on depth.  The latent
 # attention cell's step is compiled whole: 128 sequences of 2,048
-# positions, 21 scan units.
+# positions, 21 scan units; so is the hybrid cell's, one period of 9
+# Mamba-2 layers (a 537 MB f32 state each) and one NoPE attention layer.
 SERVE_WIDTHS = {
     "granite-moe-3b-a800m": dict(
         cfg=dict(d_model=1536, n_heads=24, n_kv_heads=8, head_dim=64,
@@ -151,14 +152,29 @@ SERVE_WIDTHS = {
                      mscale=0.707, mscale_all_dim=0.707),
                  n_layers=22, norm_eps=1e-6),
         batch=128, max_len=2048, rows_minor=("latent",)),
+    "granite-4.0-h-small": dict(
+        cfg=dict(d_model=4096, n_heads=32, n_kv_heads=8, head_dim=128,
+                 n_experts=72, n_experts_held=9, top_k=10, n_shared_experts=2,
+                 d_ff=768, d_ff_expert=768, capacity_factor=7.2,
+                 vocab_size=100352, tie_embeddings=True, n_layers=10,
+                 pattern=tuple(LayerSpec("attn" if i == 5 else "mamba2", "moe")
+                               for i in range(10)),
+                 nope=True, mamba_d_state=128, mamba_n_heads=128,
+                 mamba_head_dim=64, embedding_multiplier=12.0,
+                 residual_multiplier=0.22, attention_multiplier=1 / 128,
+                 logits_scaling=16.0),
+        # The conv states (13 MB a layer) may be prefetched into fast
+        # memory; the SSM states and the K/V stacks may not be copied.
+        batch=128, max_len=2048, stacks=("ssm", "k", "v"),
+        layer_copies=("ssm",)),
 }
 
 
 @pytest.mark.parametrize("model", list(SERVE_WIDTHS))
 def test_serve_step_updates_stacked_cache_in_place(one_chip, model):
     """The decode step with its cache donated: no op copies the whole
-    stacked K or V cache (or latent cache), so each step writes only the
-    new rows."""
+    stacked K or V cache (or latent cache, or SSM state), so each step
+    writes only the new rows (or the layer's state, in place)."""
     w = SERVE_WIDTHS[model]
     cfg = ModelConfig(**{"name": model, "n_layers": 3,
                          "pattern": (LayerSpec("attn", "moe"),),
@@ -175,13 +191,17 @@ def test_serve_step_updates_stacked_cache_in_place(one_chip, model):
     pos = _struct((b,), jnp.int32, one_chip)
     hlo = jax.jit(make_serve_step(cfg, Parallel()), donate_argnums=(1,)) \
         .lower(params, cache, tok, pos, None).compile().as_text()
-    for name, stack in cache["units"]["b0"].items():
-        whole = "bf16[%s]" % ",".join(map(str, stack.shape))
+    stacks = [(name, stack) for block in cache["units"].values()
+              for name, stack in block.items()
+              if name in w.get("stacks", (name,))]
+    for name, stack in stacks:
+        dt = {"bfloat16": "bf16", "float32": "f32"}[stack.dtype.name]
+        whole = "%s[%s]" % (dt, ",".join(map(str, stack.shape)))
         shapes = [whole]
-        if name in w.get("rows_minor", ()):
-            # The latent core reads its layer out of the stack: no op
-            # copies one layer's rows either.
-            shapes.append("bf16[%s]" % ",".join(map(str, stack.shape[1:])))
+        if name in w.get("rows_minor", ()) + w.get("layer_copies", ()):
+            # The latent core and the SSM state update read their layer
+            # out of the stack: no op copies one layer's rows either.
+            shapes.append("%s[%s]" % (dt, ",".join(map(str, stack.shape[1:]))))
         copies = [line.strip()[:160] for line in hlo.splitlines()
                   if (m := re.search(r"= (.*?) (copy|copy-start)\(", line))
                   and any(sh + "{" in m.group(1) for sh in shapes)]
